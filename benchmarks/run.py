@@ -60,6 +60,8 @@ import traceback
 SUITES = ["table2", "table3", "table4", "fig1", "rates", "matrix", "agg",
           "comm", "async", "train", "serve"]
 
+SUBPROCESS_SUITES = ("train", "serve")
+
 GATE_M = 32  # the gated worker count (the ROADMAP's deployment size)
 # Timing gate with a safety margin: on shared CI runners wall time is
 # noisy (neighbors, scheduler), so requiring >= 1.0 would flake on runs
@@ -86,10 +88,11 @@ def _gate_agg(records) -> list:
 
 
 def _run_bench_subprocess(module: str, smoke: bool) -> dict:
-    """Run a throughput grid in a fresh interpreter: it must set
-    --xla_force_host_platform_device_count BEFORE jax initializes (which
-    this process may already have done for another suite), and a cold
-    jit cache keeps the timing honest."""
+    """Run a throughput grid in a fresh interpreter: on the CPU it must
+    set the simulated device count BEFORE jax initializes, and a cold
+    jit cache keeps the timing honest.  ``main`` starts these suites
+    before any in-process suite touches JAX, so on a chip the child is
+    the only process holding it."""
     with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as tmp:
         path = tmp.name
     try:
@@ -147,6 +150,8 @@ def main() -> None:
                          f"XLA-sort baseline at m={GATE_M}")
     args = ap.parse_args()
     only = args.only.split(",") if args.only else SUITES
+    # subprocess suites first: a parent that has touched JAX holds the chip
+    only = sorted(only, key=lambda s: s not in SUBPROCESS_SUITES)
 
     print("name,us_per_call,derived")
     failed = []
@@ -175,7 +180,7 @@ def main() -> None:
                 from benchmarks import comm_efficiency as mod
             elif suite == "async":
                 from benchmarks import async_throughput as mod
-            elif suite in ("train", "serve"):
+            elif suite in SUBPROCESS_SUITES:
                 mod = None  # runs in a subprocess below
             else:
                 raise ValueError(f"unknown suite {suite}")

@@ -266,14 +266,10 @@ def main(argv=None) -> int:
                     default=None, metavar="PATH")
     args = ap.parse_args(argv)
 
-    import os
+    from repro.launch.runtime import simulate_cpu_devices
 
     cfg = SMOKE if args.smoke else ServeBenchConfig()
-    flags = os.environ.get("XLA_FLAGS", "")
-    if ("--xla_force_host_platform_device_count" not in flags
-            and cfg.workers > 1):
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={cfg.workers}")
+    simulate_cpu_devices(cfg.workers)
 
     out = evaluate(cfg, verbose=True)
     out["smoke"] = args.smoke

@@ -359,15 +359,12 @@ def main(argv=None) -> int:
                     help="override simulated worker count")
     args = ap.parse_args(argv)
 
-    import os
+    from repro.launch.runtime import simulate_cpu_devices
 
-    flags = os.environ.get("XLA_FLAGS", "")
     cfg = SMOKE if args.smoke else TrainBenchConfig()
     if args.workers:
         cfg = dataclasses.replace(cfg, workers=args.workers)
-    if "--xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={cfg.workers}")
+    simulate_cpu_devices(cfg.workers)
 
     out = evaluate(cfg, verbose=True)
     out["smoke"] = args.smoke

@@ -6,13 +6,12 @@ This is the "real system" example: the production train_step
 (shard_map + robust collective aggregation), the sharded data pipeline
 with per-worker Byzantine corruption, AdamW, checkpointing.
 
-Run:  PYTHONPATH=src python examples/train_lm_robust.py [--steps 300]
-(sets its own XLA_FLAGS; ~100M params, CPU-friendly settings)
+Run:  JAX_PLATFORMS=cpu PYTHONPATH=src python examples/train_lm_robust.py [--steps 300]
+(simulates the 8 devices on the CPU; ~100M params, CPU-friendly settings)
 """
-import os
+from repro.launch.runtime import simulate_cpu_devices
 
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+simulate_cpu_devices(8)
 
 import argparse
 import time

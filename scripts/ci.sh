@@ -73,10 +73,7 @@
 #                             + ruff format check on scripts/ — requires
 #                             ruff on PATH; the GitHub lint job installs it
 #
-# Env-dependent tests (newer-jax shard_map/set_mesh API, cost_analysis
-# dict-vs-list) are skipif/xfail-guarded in the test files, so the
-# pass/fail counts are clean on every jax the CI matrix installs; the
-# GitHub workflow enforces the pass floor and failure ceiling.
+# The GitHub workflow enforces the pass floor and failure ceiling.
 set -uo pipefail
 cd "$(dirname "$0")/.."
 

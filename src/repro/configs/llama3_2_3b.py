@@ -1,4 +1,4 @@
-"""llama3.2-3b [hf:meta-llama/Llama-3.2-1B family].
+"""llama3.2-3b [hf:meta-llama/Llama-3.2-3B].
 
 28L, d_model=3072, 24H (GQA kv=8), d_ff=8192, vocab=128256.
 """
@@ -17,7 +17,7 @@ CONFIG = ModelConfig(
     vocab=128256,
     rope_theta=500000.0,
     long_context_window=8192,  # SWA variant used only for long_500k decode
-    source="hf:meta-llama/Llama-3.2-1B",
+    source="hf:meta-llama/Llama-3.2-3B",
 )
 
 

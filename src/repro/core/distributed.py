@@ -64,17 +64,10 @@ from repro.core import attacks as attacks_mod
 from repro.core.attacks import AttackConfig, apply_gradient_attack
 
 
-def _axis_size_one(a: str) -> int:
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(a)
-    frame = jax.core.axis_frame(a)  # jax < 0.5 has no lax.axis_size
-    return frame if isinstance(frame, int) else frame.size
-
-
 def axis_size(axis_names: Sequence[str]) -> int:
     s = 1
     for a in axis_names:
-        s *= _axis_size_one(a)
+        s *= jax.lax.axis_size(a)
     return s
 
 
@@ -86,7 +79,7 @@ def worker_index(axis_names: Sequence[str]) -> jax.Array:
     """
     idx = jnp.int32(0)
     for a in axis_names:
-        idx = idx * _axis_size_one(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -155,6 +148,43 @@ def _unflatten_tree(flat: jax.Array, aux) -> "jax.tree_util.PyTreeDef":
     return jax.tree.unflatten(treedef, leaves)
 
 
+def _robust_scatter_rows(
+    x: jax.Array,
+    axis_names: Sequence[str],
+    method: str,
+    beta: float,
+    attack: Optional[AttackConfig],
+    agg_dtype,
+    attack_key=None,
+) -> jax.Array:
+    """Core of the bucketed strategies.
+
+    Input: local gradient block ``x`` of shape (N, ...), N divisible by
+    m. Output: this worker's aggregated bucket (N/m, ...) — rows
+    [j*N/m : (j+1)*N/m] for worker j.  Splitting only the leading dim
+    keeps the block's tiled device layout, so no relayout copy of the
+    gradient enters the program.
+    """
+    axis_names = tuple(axis_names)
+    m = axis_size(axis_names)
+    sizes = tuple(jax.lax.axis_size(a) for a in axis_names)
+    bs = x.shape[0] // m
+    # Buckets laid out per worker axis: bucket (i_0, .., i_{k-1}) goes to the
+    # worker at that mesh coordinate (flat index row-major = all_gather order).
+    rows = x.reshape(sizes + (bs,) + x.shape[1:])
+    # all_to_all each worker axis on its own bucket dim: afterwards, entry
+    # (j_0, .., j_{k-1}) is worker (j_0, .., j_{k-1})'s copy of MY bucket.
+    for dim, a in enumerate(axis_names):
+        rows = jax.lax.all_to_all(rows, a, split_axis=dim, concat_axis=dim, tiled=True)
+    # rows: (m, bs, ...) — row i is (flat) worker i's version of my bucket
+    rows = rows.reshape((m, bs) + x.shape[1:])
+    if agg_dtype is not None:
+        rows = rows.astype(agg_dtype)
+    rows = _maybe_attack(rows, attack, m, attack_key)
+    out = aggregators.get_aggregator(method, beta)(rows)
+    return out.astype(x.dtype)
+
+
 def _robust_scatter_flat(
     flat: jax.Array,
     axis_names: Sequence[str],
@@ -164,35 +194,39 @@ def _robust_scatter_flat(
     agg_dtype,
     attack_key=None,
 ) -> Tuple[jax.Array, int]:
-    """Core of the bucketed strategies.
-
-    Input: local flat gradient (G,). Output: this worker's aggregated
-    bucket (ceil(G/m),) — coordinates [j*bs : (j+1)*bs] for worker j —
-    plus the original size for unpadding by the caller.
+    """:func:`_robust_scatter_rows` of a flat gradient (G,), zero-padded to
+    a multiple of m: returns this worker's aggregated bucket (ceil(G/m),)
+    — coordinates [j*bs : (j+1)*bs] for worker j — plus the original
+    size for unpadding by the caller.
     """
-    axis_names = tuple(axis_names)
-    m = axis_size(axis_names)
-    sizes = tuple(_axis_size_one(a) for a in axis_names)
+    m = axis_size(tuple(axis_names))
     size = flat.shape[0]
-    bs = -(-size // m)  # ceil
-    pad = bs * m - size
+    pad = -size % m
     if pad:
         flat = jnp.pad(flat, (0, pad))
-    # Buckets laid out per worker axis: bucket (i_0, .., i_{k-1}) goes to the
-    # worker at that mesh coordinate (flat index row-major = all_gather order).
-    buckets = flat.reshape(sizes + (bs,))
-    # all_to_all each worker axis on its own bucket dim: afterwards, entry
-    # (j_0, .., j_{k-1}) is worker (j_0, .., j_{k-1})'s copy of MY bucket.
-    rows = buckets
-    for dim, a in enumerate(axis_names):
-        rows = jax.lax.all_to_all(rows, a, split_axis=dim, concat_axis=dim, tiled=True)
-    rows = rows.reshape(m, bs)
-    # rows: (m, bs) — row i is (flat) worker i's version of my bucket
-    if agg_dtype is not None:
-        rows = rows.astype(agg_dtype)
-    rows = _maybe_attack(rows, attack, m, attack_key)
-    out = aggregators.get_aggregator(method, beta)(rows)
-    return out.astype(flat.dtype), size
+    out = _robust_scatter_rows(flat, axis_names, method, beta, attack,
+                               agg_dtype, attack_key)
+    return out, size
+
+
+def _row_blocks(leaves, m: int):
+    """2-D ``(rows, c)`` views of a coalesced group, or None.
+
+    When every leaf has >= 2 dims, all share the last dim c, and the
+    group's total row count divides by m, the group is bucketed along
+    its rows: row-major order makes bucket j the very coordinates the
+    flat view would give it, so the estimator and the bytes on the wire
+    are unchanged.  The flat ``(m, G/m)`` view of a matrix is a relayout
+    that the TPU compiler handles in time that grows with the leaf: one
+    bf16 (32000, 2560) embedding gradient took ~100 s to compile that
+    way and ~2.5 s by rows (v5e, compiled without a chip).
+    """
+    if any(l.ndim < 2 for l in leaves) or len({l.shape[-1] for l in leaves}) > 1:
+        return None
+    mats = [l.reshape(-1, l.shape[-1]) for l in leaves]
+    if sum(x.shape[0] for x in mats) % m:
+        return None
+    return mats
 
 
 # Element cap per coalesced super-bucket (16 MiB in f32): small leaves
@@ -261,20 +295,29 @@ def robust_bucketed_agg(
     models).
     """
     if granularity == "leaf":
+        m = axis_size(tuple(axis_names))
         leaves, treedef = jax.tree.flatten(g)
         out_leaves = [None] * len(leaves)
         for grp in _coalesce_groups(leaves):
-            flat = (leaves[grp[0]].reshape(-1) if len(grp) == 1 else
-                    jnp.concatenate([leaves[i].reshape(-1) for i in grp]))
-            mine, size = _robust_scatter_flat(flat, axis_names, method, beta,
-                                              attack, agg_dtype, attack_key)
+            mats = _row_blocks([leaves[i] for i in grp], m)
+            if mats is None:
+                mats = [leaves[i].reshape(-1) for i in grp]
+            block = mats[0] if len(grp) == 1 else jnp.concatenate(mats)
+            if block.ndim == 2:
+                mine = _robust_scatter_rows(block, axis_names, method, beta,
+                                            attack, agg_dtype, attack_key)
+                size = block.shape[0]
+            else:
+                mine, size = _robust_scatter_flat(block, axis_names, method,
+                                                  beta, attack, agg_dtype,
+                                                  attack_key)
             full = jax.lax.all_gather(mine, axis_names, axis=0, tiled=True)[:size]
             off = 0
-            for i in grp:
+            for i, part in zip(grp, mats):
                 leaf = leaves[i]
-                out_leaves[i] = (full[off : off + leaf.size]
+                out_leaves[i] = (full[off : off + part.shape[0]]
                                  .reshape(leaf.shape).astype(leaf.dtype))
-                off += leaf.size
+                off += part.shape[0]
         return jax.tree.unflatten(treedef, out_leaves)
     flat, aux = _flatten_tree(g)
     mine, size = _robust_scatter_flat(flat, axis_names, method, beta, attack,

@@ -37,6 +37,7 @@ from repro.rounds import compression
 from repro.fed.async_rounds import AsyncConfig, run_async_rounds
 from repro.fed.population import ArrivalConfig, ClientPopulation, PopulationConfig
 from repro.fed.rounds import AttackMixture, RoundConfig, run_rounds
+from repro.launch import runtime
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -137,8 +138,8 @@ def _iterate_digest(w) -> str:
     return hashlib.sha256(np.asarray(w).tobytes()).hexdigest()
 
 
-def main(argv=None) -> int:
-    args = build_parser().parse_args(argv)
+def setup(args):
+    """(population, round config, attack mixture) of parsed CLI args."""
     pcfg = PopulationConfig(
         num_clients=args.clients, samples_per_client=args.samples_per_client,
         dim=args.dim, alpha=args.alpha, heterogeneity=args.heterogeneity,
@@ -156,6 +157,14 @@ def main(argv=None) -> int:
             AttackConfig(name=a.strip(), alpha=args.alpha,
                          scale=args.attack_scale, shift=args.attack_shift)
             for a in args.attack.split(",") if a.strip())
+    return pop, rcfg, AttackMixture(attacks, schedule=args.schedule)
+
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
+    runtime.enable_compile_cache()
+    pop, rcfg, mixture = setup(args)
+    pcfg = pop.cfg
     print(f"population: {pcfg.num_clients} clients "
           f"({pcfg.num_byzantine()} Byzantine), d={pcfg.dim}, "
           f"n={pcfg.samples_per_client}/client, "
@@ -164,7 +173,6 @@ def main(argv=None) -> int:
           f"(chunks of {rcfg.chunk_clients}), method={rcfg.method}, "
           f"nbins={rcfg.nbins}, tau={rcfg.local_steps}, "
           f"compression={rcfg.compression}")
-    mixture = AttackMixture(attacks, schedule=args.schedule)
     ckpt_kwargs = dict(
         ckpt_dir=args.ckpt_dir,
         ckpt_every=args.ckpt_every if args.ckpt_dir else 0,

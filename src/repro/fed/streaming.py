@@ -29,10 +29,6 @@ import jax.numpy as jnp
 from repro.kernels import histogram_agg as H
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @dataclasses.dataclass(frozen=True)
 class SketchConfig:
     nbins: int = 256
@@ -40,7 +36,8 @@ class SketchConfig:
     block: int = 512  # pallas lane-block (multiple of 128)
 
     def use_pallas(self) -> bool:
-        return self.backend == "pallas" or (self.backend == "auto" and _on_tpu())
+        return self.backend == "pallas" or (
+            self.backend == "auto" and jax.default_backend() == "tpu")
 
 
 # ------------------------------------------------------------------ pass 1
@@ -53,7 +50,7 @@ def minmax_init(d: int) -> tuple[jax.Array, jax.Array]:
 def minmax_update(state, chunk: jax.Array, cfg: SketchConfig = SketchConfig()):
     lo, hi = state
     if cfg.use_pallas():
-        clo, chi = H.minmax_pallas(chunk, block=cfg.block, interpret=not _on_tpu())
+        clo, chi = H.minmax_pallas(chunk, block=cfg.block)
     else:
         cf = chunk.astype(jnp.float32)
         clo, chi = jnp.min(cf, axis=0), jnp.max(cf, axis=0)
@@ -74,7 +71,7 @@ def hist_update(state, chunk: jax.Array, lo: jax.Array, width: jax.Array,
     counts, sums = state
     if cfg.use_pallas():
         dc, ds = H.histogram_pallas(chunk, lo, width, nbins=counts.shape[0],
-                                    block=cfg.block, interpret=not _on_tpu(),
+                                    block=cfg.block,
                                     with_sums=sums is not None)
         return counts + dc, (sums + ds if sums is not None else None)
     return H.hist_update(counts, sums, chunk, lo, width)

@@ -10,7 +10,9 @@
   (min/max + bin counts/sums) for federated-scale m, plus the pure-jnp
   CDF-inversion helpers shared by fed.streaming and core.distributed
 - ops.py: jit'd dispatch wrappers (pallas on TPU, network/XLA on CPU)
+- mode.py: where the kernels run (Mosaic on a TPU, interpreted
+  elsewhere) and a per-kernel record of how each was traced
 - ref.py: pure-jnp jnp.sort oracle used by the allclose tests
 """
 from repro.kernels import (  # noqa: F401
-    histogram_agg, ops, ref, robust_agg, selection_network)
+    histogram_agg, mode, ops, ref, robust_agg, selection_network)

@@ -50,6 +50,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import mode
+
 # --------------------------------------------------------------------------
 # pure-jnp sketch math (shared by fed.streaming, core.distributed, tests)
 # --------------------------------------------------------------------------
@@ -189,8 +191,8 @@ def trimmed_mean_from_hist(
 
 def _minmax_kernel(x_ref, lo_ref, hi_ref):
     x = x_ref[...].astype(jnp.float32)
-    lo_ref[...] = jnp.min(x, axis=0)
-    hi_ref[...] = jnp.max(x, axis=0)
+    lo_ref[...] = jnp.min(x, axis=0, keepdims=True)
+    hi_ref[...] = jnp.max(x, axis=0, keepdims=True)
 
 
 def _pad_cols(x: jnp.ndarray, mult: int, fill=0.0) -> tuple[jnp.ndarray, int]:
@@ -203,14 +205,16 @@ def _pad_cols(x: jnp.ndarray, mult: int, fill=0.0) -> tuple[jnp.ndarray, int]:
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def minmax_pallas(x: jnp.ndarray, block: int = 512, interpret: bool = True):
+def minmax_pallas(x: jnp.ndarray, block: int = 512,
+                  interpret: Optional[bool] = None):
     """Per-coordinate (min, max) of a ``(rows, n)`` chunk → two (n,) f32.
 
     Pass-1 building block: combine across chunks with jnp.minimum/maximum.
-    ``interpret=True`` on CPU; Mosaic lowering on TPU.
+    ``interpret=None``: Mosaic lowering on TPU, interpreted elsewhere.
     """
     assert x.ndim == 2, x.shape
     assert block % 128 == 0, "block must be a multiple of the 128-lane width"
+    interpret = mode.resolve("minmax_pallas", interpret)
     rows = x.shape[0]
     xp, n = _pad_cols(x, block)
     grid = (xp.shape[1] // block,)
@@ -218,17 +222,13 @@ def minmax_pallas(x: jnp.ndarray, block: int = 512, interpret: bool = True):
         _minmax_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((rows, block), lambda i: (0, i))],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((xp.shape[1],), jnp.float32),
-            jax.ShapeDtypeStruct((xp.shape[1],), jnp.float32),
-        ],
+        # (1, block) rows, not (block,): a 1-D output block must match
+        # XLA's 1024-element tiling, which the default block of 512 does not
+        out_specs=[pl.BlockSpec((1, block), lambda i: (0, i))] * 2,
+        out_shape=[jax.ShapeDtypeStruct((1, xp.shape[1]), jnp.float32)] * 2,
         interpret=interpret,
     )(xp)
-    return lo[:n], hi[:n]
+    return lo[0, :n], hi[0, :n]
 
 
 def _hist_kernel(x_ref, lo_ref, w_ref, c_ref, s_ref=None, *, nbins: int):
@@ -257,7 +257,7 @@ def histogram_pallas(
     width: jnp.ndarray,
     nbins: int = 128,
     block: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     with_sums: bool = True,
 ):
     """Per-chunk bin (counts, sums) of ``x`` (rows, n) → two (nbins, n) f32.
@@ -270,6 +270,7 @@ def histogram_pallas(
     """
     assert x.ndim == 2, x.shape
     assert block % 128 == 0
+    interpret = mode.resolve("histogram_pallas", interpret)
     rows = x.shape[0]
     xp, n = _pad_cols(x, block)
     # padded lanes get lo=0, width=0 -> all rows in bin 0; sliced off below

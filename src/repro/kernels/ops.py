@@ -4,7 +4,8 @@
 the coordinate space, dispatches to the Pallas kernel (interpret mode on
 CPU, Mosaic on TPU), and restores the shape. Backends:
 
-- ``pallas``   the selection-network Pallas kernels (Mosaic on TPU);
+- ``pallas``   the selection-network Pallas kernels (always Mosaic on a
+  TPU — see kernels/mode.py);
 - ``network``  the same pruned selection program executed as unrolled
   jnp min/max — XLA-compiled, the fast CPU path and the benchmark
   subject (no interpreter overhead, no sort machinery);
@@ -24,8 +25,12 @@ from repro.kernels import ref, robust_agg, selection_network as SN
 from repro.kernels.selection_network import NETWORK_MAX_M
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _auto_backend(m: int) -> str:
+    """``auto``: the Pallas kernels on a TPU; off it, the XLA-compiled
+    network for small m and the sort oracle above the network limit."""
+    if jax.default_backend() == "tpu":
+        return "pallas"
+    return "network" if 2 <= m <= NETWORK_MAX_M else "xla"
 
 
 def _check_network_m(m: int) -> None:
@@ -49,14 +54,12 @@ def robust_aggregate(
     m = x.shape[0]
     flat = x.reshape(m, -1)
     if backend == "auto":
-        backend = "pallas" if _on_tpu() else (
-            "network" if 2 <= m <= NETWORK_MAX_M else "xla")
+        backend = _auto_backend(m)
     elif backend == "network":
         _check_network_m(m)
-    interpret = not _on_tpu()
     if method == "median":
         if backend == "pallas":
-            out = robust_agg.median_pallas(flat, block=block, interpret=interpret)
+            out = robust_agg.median_pallas(flat, block=block)
         elif backend == "network":
             out = SN.median_select(flat)
         else:
@@ -64,8 +67,7 @@ def robust_aggregate(
     elif method == "trimmed_mean":
         trim = int(beta * m)
         if backend == "pallas":
-            out = robust_agg.trimmed_mean_pallas(flat, trim, block=block,
-                                                 interpret=interpret)
+            out = robust_agg.trimmed_mean_pallas(flat, trim, block=block)
         elif backend == "network":
             out = (SN.trimmed_mean_select(flat, trim) if trim
                    else jnp.mean(flat.astype(jnp.float32), axis=0).astype(flat.dtype))
@@ -94,13 +96,11 @@ def fused_median_trimmed(
     trim = int(beta * m)
     flat = x.reshape(m, -1)
     if backend == "auto":
-        backend = "pallas" if _on_tpu() else (
-            "network" if 2 <= m <= NETWORK_MAX_M else "xla")
+        backend = _auto_backend(m)
     elif backend == "network":
         _check_network_m(m)
     if backend == "pallas":
-        med, tm = robust_agg.fused_median_trimmed_pallas(
-            flat, trim, block=block, interpret=not _on_tpu())
+        med, tm = robust_agg.fused_median_trimmed_pallas(flat, trim, block=block)
     elif backend == "network":
         med, tm = SN.median_and_trimmed_select(flat, trim)
     else:

@@ -22,7 +22,10 @@ benchmark matrix gets median *and* trimmed mean in ONE HBM pass instead
 of two.
 
 Layout reasoning (HBM→VMEM): each grid step streams an (m, BLOCK) tile
-(m·BLOCK·dtype bytes) in and (BLOCK,) out; with BLOCK=1024 and m=32 in
+(m·BLOCK·dtype bytes) in and a (1, BLOCK) row out — 2-D, because Mosaic
+tiles a 1-D output block at BLOCK while XLA tiles the 1-D array at 1024,
+and the chip's compiler refuses the mismatch for any BLOCK that is not a
+multiple of 1024; with BLOCK=1024 and m=32 in
 f32 that is a 128 KiB in-tile — far below the ~16 MiB VMEM budget, so the
 pipeline can double-buffer freely. Arithmetic intensity is O(#comparators/m)
 passes over the tile, i.e. the op is HBM-bandwidth-bound, which is why
@@ -34,34 +37,35 @@ estimators.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import selection_network as SN
+from repro.kernels import mode, selection_network as SN
 
 
 def _median_kernel(x_ref, o_ref, *, comparators):
     x = x_ref[...]
     m = x.shape[0]
     rows = SN.apply_network([x[i] for i in range(m)], comparators)
-    o_ref[...] = SN.median_from_rows(rows, m, x.dtype)
+    o_ref[...] = SN.median_from_rows(rows, m, x.dtype)[None, :]
 
 
 def _trimmed_mean_kernel(x_ref, o_ref, *, trim: int, comparators):
     x = x_ref[...]
     m = x.shape[0]
     rows = SN.apply_network([x[i] for i in range(m)], comparators)
-    o_ref[...] = SN.band_mean_from_rows(rows, m, trim, x.dtype)
+    o_ref[...] = SN.band_mean_from_rows(rows, m, trim, x.dtype)[None, :]
 
 
 def _fused_kernel(x_ref, med_ref, tm_ref, *, trim: int, comparators):
     x = x_ref[...]
     m = x.shape[0]
     rows = SN.apply_network([x[i] for i in range(m)], comparators)
-    med_ref[...] = SN.median_from_rows(rows, m, x.dtype)
-    tm_ref[...] = SN.band_mean_from_rows(rows, m, trim, x.dtype)
+    med_ref[...] = SN.median_from_rows(rows, m, x.dtype)[None, :]
+    tm_ref[...] = SN.band_mean_from_rows(rows, m, trim, x.dtype)[None, :]
 
 
 def _pad_to(x: jnp.ndarray, mult: int) -> tuple[jnp.ndarray, int]:
@@ -73,16 +77,18 @@ def _pad_to(x: jnp.ndarray, mult: int) -> tuple[jnp.ndarray, int]:
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def median_pallas(x: jnp.ndarray, block: int = 1024, interpret: bool = True) -> jnp.ndarray:
+def median_pallas(x: jnp.ndarray, block: int = 1024,
+                  interpret: Optional[bool] = None) -> jnp.ndarray:
     """Coordinate-wise median of x: (m, n) -> (n,) via Pallas.
 
-    ``interpret=True`` executes the kernel body in Python on CPU (this
-    container); on a real TPU pass ``interpret=False`` for the Mosaic
-    lowering. ``block`` must be a multiple of 128 (lane width).
+    ``interpret=None`` lowers through Mosaic on a TPU and interprets the
+    kernel body elsewhere (:mod:`repro.kernels.mode`). ``block`` must be
+    a multiple of 128 (lane width).
     """
     assert x.ndim == 2, x.shape
     assert block % 128 == 0, "block must be a multiple of the 128-lane width"
     m = x.shape[0]
+    interpret = mode.resolve("median_pallas", interpret)
     prog = SN.median_program(m)
     xp, n = _pad_to(x, block)
     grid = (xp.shape[1] // block,)
@@ -90,22 +96,24 @@ def median_pallas(x: jnp.ndarray, block: int = 1024, interpret: bool = True) -> 
         functools.partial(_median_kernel, comparators=prog.comparators),
         grid=grid,
         in_specs=[pl.BlockSpec((m, block), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((xp.shape[1],), x.dtype),
+        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, xp.shape[1]), x.dtype),
         interpret=interpret,
     )(xp)
-    return out[:n]
+    return out[0, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("trim", "block", "interpret"))
 def trimmed_mean_pallas(
-    x: jnp.ndarray, trim: int, block: int = 1024, interpret: bool = True
+    x: jnp.ndarray, trim: int, block: int = 1024,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Coordinate-wise trimmed mean of x: (m, n) -> (n,), trimming ``trim``
     rows at each end (trim = floor(beta*m))."""
     assert x.ndim == 2, x.shape
     assert block % 128 == 0
     m = x.shape[0]
+    interpret = mode.resolve("trimmed_mean_pallas", interpret)
     assert 0 <= trim and 2 * trim < m, (trim, m)
     prog = SN.trimmed_program(m, trim)
     xp, n = _pad_to(x, block)
@@ -115,16 +123,17 @@ def trimmed_mean_pallas(
                           comparators=prog.comparators),
         grid=grid,
         in_specs=[pl.BlockSpec((m, block), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((xp.shape[1],), x.dtype),
+        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, xp.shape[1]), x.dtype),
         interpret=interpret,
     )(xp)
-    return out[:n]
+    return out[0, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("trim", "block", "interpret"))
 def fused_median_trimmed_pallas(
-    x: jnp.ndarray, trim: int, block: int = 1024, interpret: bool = True
+    x: jnp.ndarray, trim: int, block: int = 1024,
+    interpret: Optional[bool] = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Median AND trimmed mean of x: (m, n) -> ((n,), (n,)) in one HBM pass.
 
@@ -136,6 +145,7 @@ def fused_median_trimmed_pallas(
     assert x.ndim == 2, x.shape
     assert block % 128 == 0
     m = x.shape[0]
+    interpret = mode.resolve("fused_median_trimmed_pallas", interpret)
     assert 0 <= trim and 2 * trim < m, (trim, m)
     prog = SN.fused_program(m, trim)
     xp, n = _pad_to(x, block)
@@ -144,14 +154,8 @@ def fused_median_trimmed_pallas(
         functools.partial(_fused_kernel, trim=trim, comparators=prog.comparators),
         grid=grid,
         in_specs=[pl.BlockSpec((m, block), lambda i: (0, i))],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((xp.shape[1],), x.dtype),
-            jax.ShapeDtypeStruct((xp.shape[1],), x.dtype),
-        ],
+        out_specs=[pl.BlockSpec((1, block), lambda i: (0, i))] * 2,
+        out_shape=[jax.ShapeDtypeStruct((1, xp.shape[1]), x.dtype)] * 2,
         interpret=interpret,
     )(xp)
-    return med[:n], tm[:n]
+    return med[0, :n], tm[0, :n]

@@ -13,12 +13,27 @@ or 32 workers).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    jax >= 0.7 makes mesh axes ``Explicit`` unless told otherwise, and
+    explicit sharding rejects ops whose output sharding it cannot infer
+    (the embedding gather among them) even on a 1x1 mesh.  The repo's
+    steps place shardings with ``NamedSharding``/``shard_map`` and let
+    GSPMD propagate the rest, which is the Auto contract — so every mesh
+    in the repo is built here.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def worker_axes(mesh) -> tuple:
@@ -42,7 +57,8 @@ def num_workers(mesh) -> int:
 
 
 def make_debug_mesh(data: int = 4, model: int = 2, pod: int = 0):
-    """Small mesh for CPU tests (requires xla_force_host_platform_device_count)."""
+    """Small mesh on the first ``pod*data*model`` devices: one host's chips,
+    or simulated CPU devices (``xla_force_host_platform_device_count``)."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -346,9 +346,7 @@ def make_step_body(
     All build-time validation lives here — attack access vs strategy,
     adaptive/randomized rejection, local-steps constraints — so the two
     integration points cannot drift.  When every model axis has size 1
-    the ShardCtx drops them: constraints over size-1 axes are no-ops,
-    and older jax's experimental shard_map (all mesh axes manual) cannot
-    emit them inside the manual region at all.
+    the ShardCtx drops them: constraints over size-1 axes are no-ops.
     """
     if attack is not None and attack.name != "none" and attack.alpha > 0:
         atk_spec, _ = attack.resolve()  # raises early on unknown names
@@ -440,7 +438,7 @@ def make_step_body(
             if comp_spec_obj.randomized:
                 ckey = jax.random.fold_in(
                     jax.random.fold_in(jax.random.PRNGKey(11), step),
-                    rounds_dist._worker_index(waxes))
+                    distributed.worker_index(waxes))
             grads, new_res = comp_lib.compress_tree(
                 pcfg.compression, grads, key=ckey, residual=comp[0])
             comp = jnp.expand_dims(new_res, 0)
@@ -593,13 +591,12 @@ def make_decode_step(cfg: ModelConfig, mesh):
 def _serve_ctx(mesh) -> ShardCtx:
     """ShardCtx of the slot-pool serving steps: no batch axes (the pool is
     a replicated vmap over slots, not a worker-sharded batch), model axes
-    only when they can actually constrain — size-1 constraints are no-ops,
-    and without ``jax.set_mesh`` (older jax) bare-PartitionSpec constraints
-    have no mesh context to resolve against; sharding still propagates
-    from the parameter NamedShardings."""
+    only when they can actually constrain — size-1 constraints are
+    no-ops; sharding still propagates from the parameter
+    NamedShardings."""
     shp = mesh_lib.mesh_shape_dict(mesh)
     model_axes = mesh_lib.model_axes(mesh)
-    if all(shp.get(a, 1) == 1 for a in model_axes) or not hasattr(jax, "set_mesh"):
+    if all(shp.get(a, 1) == 1 for a in model_axes):
         model_axes = ()
     return ShardCtx(batch_axes=(), model_axes=model_axes, mesh_shape=shp)
 

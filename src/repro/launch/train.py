@@ -5,15 +5,18 @@ harness (zero host syncs inside a window) over ``steps.make_step_body``
 — robust aggregation fused into the sharded train step, engine attacks
 applied in-step with per-micro-step key folding.
 
-Runs on whatever devices exist (CPU debug mesh by default — set
-XLA_FLAGS=--xla_force_host_platform_device_count=N first for a
-multi-worker simulation); on a TPU pod the same driver runs with
-``--mesh single|multi`` production meshes.
+Runs on the devices JAX finds and prints which.  The debug mesh needs
+``--workers`` x ``--model-par`` of them: one TPU chip runs
+``--workers 1``; on the CPU, simulate more with
+``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=N``.
+On a TPU pod the same CLI runs with ``--mesh single|multi``
+production meshes.  The compile cache follows
+``JAX_COMPILATION_CACHE_DIR`` (default ``.jax_cache/`` in the checkout).
 
-Example (8 simulated devices, 8 data-parallel workers, two Byzantine
+Example (8 simulated CPU devices, 8 data-parallel workers, two Byzantine
 workers running ALIE, bucketed median aggregation, 16-step windows):
 
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
   PYTHONPATH=src python -m repro.launch.train --config llama3.2-3b --smoke \\
       --steps 64 --device-steps 16 --workers 8 \\
       --strategy bucketed --agg median --attack alie --attack-alpha 0.25
@@ -32,7 +35,7 @@ from repro.checkpoint import save as save_ckpt
 from repro.configs import ParallelConfig, TrainConfig, get_config, get_smoke_config
 from repro.core.attacks import AttackConfig
 from repro.data.pipeline import DataConfig
-from repro.launch import trainer
+from repro.launch import runtime, trainer
 from repro.launch.mesh import make_debug_mesh, make_production_mesh, num_workers
 from repro.rounds import compression
 
@@ -90,14 +93,17 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    runtime.enable_compile_cache()
     cfg = get_smoke_config(args.config) if args.smoke else get_config(args.config)
     if args.mesh == "debug":
         mesh = make_debug_mesh(args.workers, args.model_par)
     else:
         mesh = make_production_mesh(multi_pod=(args.mesh == "multi"))
     m = num_workers(mesh)
+    dev = mesh.devices.flat[0]
     print(f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} workers={m} "
-          f"device_steps={args.device_steps}")
+          f"device_steps={args.device_steps} device={dev.platform}:"
+          f"{dev.device_kind}")
 
     attack = AttackConfig(args.attack, args.attack_alpha)
     if args.strategy == "psum" and args.agg != "mean":

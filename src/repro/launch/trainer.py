@@ -28,11 +28,6 @@ while-loop idiom, SNIPPETS.md):
 loop over ``make_train_step`` (pinned by tests/test_trainer.py): the
 scan body is traced once, so chunking the same step sequence into
 windows of any size replays the identical HLO per step.
-
-Old-jax note: ``shard_map_compat`` runs the window on jax versions
-without ``jax.shard_map``, where ALL mesh axes are manual — tensor
-parallelism (model axis > 1) needs the newer partial-manual API and is
-rejected with a clear error there.
 """
 from __future__ import annotations
 
@@ -52,7 +47,6 @@ from repro.launch import steps
 from repro.models import transformer as T
 from repro.optim.optimizers import Optimizer, get_optimizer
 from repro.rounds import compression as comp_lib
-from repro.rounds import distributed as rounds_dist
 
 
 # ---------------------------------------------------------------------------
@@ -67,13 +61,7 @@ def init_state(cfg: ModelConfig, mesh, opt: Optimizer, seed: int = 0,
     sums.  ``seed`` seeds both the param init and the attack-key base
     (seed 0 reproduces ``make_train_step``'s fixed ``PRNGKey(0)``)."""
     params = T.init_params(cfg, jax.random.PRNGKey(seed))
-    fsdp = pcfg is not None and pcfg.param_mode == "fsdp"
-    if fsdp:
-        pshard, _ = steps.fsdp_param_shardings(cfg, mesh)
-    else:
-        pshard = steps.param_shardings(cfg, mesh)
-    params = jax.tree.map(jax.device_put, params, pshard)
-    return {
+    state = {
         "params": params,
         "opt_state": opt.init(params),
         # per-worker compression residual ((m, D) zeros for error-feedback
@@ -84,6 +72,16 @@ def init_state(cfg: ModelConfig, mesh, opt: Optimizer, seed: int = 0,
         "key": jax.random.PRNGKey(seed),
         "metrics": zero_metrics(),
     }
+    return jax.device_put(state, state_shardings(cfg, mesh, opt, pcfg))
+
+
+def state_shardings(cfg: ModelConfig, mesh, opt: Optimizer,
+                    pcfg: Optional[ParallelConfig] = None):
+    """The sharding of every window-state leaf: where :func:`init_state`
+    places the state and what the window step returns, so the second
+    window's inputs match the first's and reuse its executable."""
+    return jax.tree.map(lambda s: s.sharding,
+                        abstract_state(cfg, mesh, opt, pcfg))
 
 
 def zero_metrics() -> Dict[str, jax.Array]:
@@ -138,13 +136,6 @@ def make_window_step(
     """
     if device_steps < 1:
         raise ValueError(f"device_steps must be >= 1, got {device_steps}")
-    shp = mesh_lib.mesh_shape_dict(mesh)
-    if not hasattr(jax, "shard_map") and any(
-            shp.get(a, 1) > 1 for a in mesh_lib.model_axes(mesh)):
-        raise NotImplementedError(
-            "model-parallel training (model axis > 1) needs jax.shard_map's "
-            "partial-manual axes; this jax version only has the experimental "
-            "all-manual API — use a data-parallel-only mesh (model size 1)")
     sb = steps.make_step_body(cfg, pcfg, mesh, opt, attack)
 
     def window(state, batches):
@@ -180,9 +171,11 @@ def make_window_step(
              "comp": sb.comp_spec, "step": P(),
              "key": P(), "metrics": P()}
     wbspec = _window_batch_spec(sb.batch_spec)
-    smapped = rounds_dist.shard_map_compat(
-        window, mesh, (sspec, wbspec), sspec, axis_names=sb.waxes)
-    return jax.jit(smapped, donate_argnums=(0,))
+    smapped = jax.shard_map(window, mesh=mesh, in_specs=(sspec, wbspec),
+                            out_specs=sspec, axis_names=frozenset(sb.waxes),
+                            check_vma=False)
+    return jax.jit(smapped, donate_argnums=(0,),
+                   out_shardings=state_shardings(cfg, mesh, opt, pcfg))
 
 
 # ---------------------------------------------------------------------------
@@ -352,14 +345,10 @@ def train_loop(
             snap, host = round_engine.load_snapshot(
                 ckpt_dir, dict(state, round=jnp.int32(0)), rnd)
             snap.pop("round")
-            # restored leaves go back to the template's MESH shardings
-            # (the donated window step was compiled against them);
-            # scalar/key leaves stay uncommitted so jit replicates them
-            # exactly like the fresh-init path
-            state = jax.tree.map(
-                lambda t, v: (jax.device_put(v, t.sharding)
-                              if isinstance(t.sharding, NamedSharding)
-                              else jnp.asarray(v)), state, snap)
+            # restored leaves go back to the template's mesh shardings
+            # (the donated window step was compiled against them)
+            state = jax.tree.map(lambda t, v: jax.device_put(v, t.sharding),
+                                 state, snap)
             history = list(host.get("history", []))
             start_w = int(state["step"]) // ds
     snapshot = {k: float(v) for k, v in state["metrics"].items()}

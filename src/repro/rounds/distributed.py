@@ -36,32 +36,6 @@ from repro.rounds import compression as comp_lib
 from repro.rounds.one_round import OneRoundConfig
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, axis_names=None):
-    """``jax.shard_map`` on current jax, ``jax.experimental.shard_map`` on
-    older versions (check_vma vs check_rep kwarg split) — the round
-    programs only need structural manual-axes semantics both provide."""
-    if hasattr(jax, "shard_map"):
-        kwargs = {"check_vma": False}
-        if axis_names is not None:
-            kwargs["axis_names"] = frozenset(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
-def _worker_index(axis_names):
-    """Linearized index of this worker over the manual worker axes
-    (row-major, matching the gathered-row order).  ``psum(1, a)`` is the
-    axis size on every jax version the repo supports."""
-    idx = jnp.int32(0)
-    for a in axis_names:
-        idx = idx * jax.lax.psum(jnp.int32(1), a) + jax.lax.axis_index(a)
-    return idx
-
-
 def aggregate_by_strategy(
     g,
     axis_names: Sequence[str],
@@ -100,7 +74,7 @@ def aggregate_by_strategy(
         base = comp_key if comp_key is not None else jax.random.PRNGKey(13)
         key = None
         if cspec.randomized:  # per-worker stochastic draws
-            key = jax.random.fold_in(base, _worker_index(axis_names))
+            key = jax.random.fold_in(base, distributed.worker_index(axis_names))
         elif cspec.shared_key:  # one public per-round map for ALL workers
             key = base
         g, _ = comp_lib.compress_tree(compression, g, key=key)
@@ -207,8 +181,9 @@ def make_local_update_round(
             comp_key=jax.random.fold_in(jax.random.PRNGKey(11), r))
         return jax.tree.map(lambda p, dd: p - eta * dd, w, d_agg)
 
-    f = shard_map_compat(body, mesh, (P(), P(entry), P()), P(),
-                         axis_names=axis_names)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P(), P(entry), P()),
+                      out_specs=P(), axis_names=frozenset(axis_names),
+                      check_vma=False)
     return jax.jit(f)
 
 
@@ -258,5 +233,6 @@ def one_round_distributed(
 
     entry = axis_names if len(axis_names) > 1 else axis_names[0]
     in_specs = jax.tree.map(lambda _: P(entry), worker_data)
-    f = shard_map_compat(body, mesh, (in_specs,), P(), axis_names=axis_names)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(in_specs,), out_specs=P(),
+                      axis_names=frozenset(axis_names), check_vma=False)
     return jax.jit(f)(worker_data)

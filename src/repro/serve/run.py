@@ -7,7 +7,7 @@ into the running pool.
 
 Smoke run on the debug mesh (the CI serve smoke)::
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=2 \\
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=2 \\
     PYTHONPATH=src python -m repro.serve.run --smoke --arch llama3_2_3b \\
         --workers 2 --model-par 1 --requests 24 --alpha 0.25 \\
         --attack feedback_flip
@@ -80,14 +80,12 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
 
-    import contextlib
-
     import jax
     import jax.flatten_util
 
     from repro.configs import get_config, get_smoke_config
     from repro.fed.population import ArrivalConfig
-    from repro.launch import steps
+    from repro.launch import runtime, steps
     from repro.launch.mesh import make_debug_mesh, make_production_mesh
     from repro.models import transformer as T
     from repro.serve.adapt import AdaptConfig, FeedbackAdapter
@@ -95,15 +93,12 @@ def main(argv=None) -> int:
         ServeConfig, ServeEngine, latency_stats, serve_stream)
     from repro.serve.traffic import TrafficConfig, VirtualUsers
 
+    runtime.enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.mesh == "debug":
         mesh = make_debug_mesh(args.workers, args.model_par)
     else:
         mesh = make_production_mesh(multi_pod=(args.mesh == "multi"))
-    # jax.set_mesh is the newer-jax surface; constraints degrade gracefully
-    # without it (launch.steps._serve_ctx), so serving runs on both legs
-    mesh_ctx = (jax.set_mesh(mesh) if hasattr(jax, "set_mesh")
-                else contextlib.nullcontext())
 
     scfg = ServeConfig(slots=args.slots, prompt_len=args.prompt_len,
                        max_new=args.max_new, eos_id=args.eos_id,
@@ -127,7 +122,7 @@ def main(argv=None) -> int:
           f"({tcfg.num_byz_shards} Byzantine via {tcfg.attack!r} at "
           f"alpha={tcfg.alpha}), latency={args.latency}")
 
-    with mesh_ctx:
+    with jax.set_mesh(mesh):
         params = T.init_params(cfg, jax.random.PRNGKey(args.seed))
         pshard = steps.param_shardings(cfg, mesh)
         params = jax.tree.map(jax.device_put, params, pshard)

@@ -7,38 +7,5 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax  # noqa: E402
-import pytest  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
-
-# ---------------------------------------------------------------------------
-# Environment guards (jax version / platform), shared by the test files.
-#
-# The CI tier-1 job runs BOTH sides of each guard (jax matrix in
-# .github/workflows/ci.yml): on the pinned older jax these tests skip; on
-# current jax they run.  Keeping them as skips (not failures) keeps the
-# tier-1 pass/fail counts clean so the workflow can enforce a hard
-# failure ceiling.
-# ---------------------------------------------------------------------------
-
-# jax.shard_map / jax.set_mesh graduated from jax.experimental in newer
-# jax; the production steps (launch/steps.py) and several tests pin the
-# public API deliberately (the experimental one differs: check_rep vs
-# check_vma, no axis_names).
-HAS_JAX_SHARD_MAP = hasattr(jax, "shard_map")
-HAS_JAX_SET_MESH = hasattr(jax, "set_mesh")
-
-ON_TPU = jax.default_backend() == "tpu"
-
-requires_jax_shard_map = pytest.mark.skipif(
-    not HAS_JAX_SHARD_MAP,
-    reason="needs the public jax.shard_map API (newer jax); "
-           "jax.experimental.shard_map has different kwargs",
-)
-requires_jax_set_mesh = pytest.mark.skipif(
-    not HAS_JAX_SET_MESH,
-    reason="needs jax.set_mesh (newer jax)",
-)
-requires_tpu = pytest.mark.skipif(
-    not ON_TPU, reason="TPU-only lowering (Mosaic)",
-)

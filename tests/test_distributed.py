@@ -9,8 +9,6 @@ import subprocess
 import sys
 import textwrap
 
-from conftest import requires_jax_set_mesh, requires_jax_shard_map
-
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -30,27 +28,20 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core import distributed, aggregators
 from repro.core.attacks import AttackConfig
+from repro.launch.mesh import make_mesh
 """
 
-# Version-compat shard_map wrapper: the collective-batching tests assert
-# structural properties (collective counts in the jaxpr) that hold on any
-# jax, so they use whichever shard_map API the environment provides
-# instead of pinning the newer jax.shard_map like the tests above.
+# shard_map with every mesh axis manual, for the collective-batching tests
 SMAP = PRELUDE + """
 def smap(f, mesh, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 """
 
 
-@requires_jax_shard_map
 def test_gather_agg_matches_oracle():
     run_sub(PRELUDE + """
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 m = 8
 g_all = np.random.default_rng(0).standard_normal((m, 40)).astype(np.float32)
 
@@ -65,10 +56,9 @@ print("OK")
 """)
 
 
-@requires_jax_shard_map
 def test_bucketed_agg_matches_gather_and_oracle():
     run_sub(PRELUDE + """
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 m = 8
 rng = np.random.default_rng(1)
 ga = rng.standard_normal((m, 37)).astype(np.float32)  # odd size -> padding
@@ -94,10 +84,9 @@ print("OK")
 """)
 
 
-@requires_jax_shard_map
 def test_bucketed_leaf_vs_flat_granularity():
     run_sub(PRELUDE + """
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 rng = np.random.default_rng(9)
 ga = rng.standard_normal((8, 37)).astype(np.float32)
 gb = rng.standard_normal((8, 3, 5)).astype(np.float32)
@@ -118,12 +107,11 @@ print("OK")
 """)
 
 
-@requires_jax_shard_map
 def test_bucketed_multi_axis_exact_global_median():
     """pod×data (2×4): bucketed a2a aggregation = global median over all 8
     workers (NOT median-of-medians)."""
     run_sub(PRELUDE + """
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 m = 8
 g_all = np.random.default_rng(2).standard_normal((m, 26)).astype(np.float32)
 
@@ -138,13 +126,12 @@ print("OK")
 """)
 
 
-@requires_jax_shard_map
 def test_hierarchical_median_of_medians():
     """Hierarchical (pod-local median, then cross-pod median) is a
     DIFFERENT estimator from the global median — verify it equals the
     explicit two-level oracle, not the global one."""
     run_sub(PRELUDE + """
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 g_all = np.random.default_rng(11).standard_normal((8, 12)).astype(np.float32)
 
 @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(("pod", "data")), out_specs=P(),
@@ -161,12 +148,11 @@ print("OK")
 """)
 
 
-@requires_jax_shard_map
 def test_gradient_attack_applied_at_aggregation():
     """Byzantine rows injected at the aggregation point: mean breaks,
     median survives."""
     run_sub(PRELUDE + """
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 g_all = np.ones((8, 16), np.float32)
 atk = AttackConfig("large_value", alpha=0.25, scale=1e6)
 
@@ -185,10 +171,9 @@ print("OK")
 """)
 
 
-@requires_jax_shard_map
 def test_trimmed_mean_distributed():
     run_sub(PRELUDE + """
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 g_all = np.random.default_rng(3).standard_normal((8, 33)).astype(np.float32)
 
 @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("data"), out_specs=P(),
@@ -203,12 +188,11 @@ print("OK")
 """)
 
 
-@requires_jax_shard_map
 def test_robust_param_gather_fsdp_bwd():
     """custom_vjp param gather: forward = all-gather; backward = robust
     reduce-scatter (exact coordinate-wise median of per-worker grads)."""
     run_sub(PRELUDE + """
-mesh = jax.make_mesh((4,), ("data",))
+mesh = make_mesh((4,), ("data",))
 m = 4
 w_full = np.random.default_rng(4).standard_normal((8, 3)).astype(np.float32)
 x_all = np.random.default_rng(5).standard_normal((m, 6, 8)).astype(np.float32)
@@ -238,7 +222,6 @@ print("OK")
 """)
 
 
-@requires_jax_set_mesh
 def test_end_to_end_train_step_robustness():
     """Full production train step on a 4x2 debug mesh: median training
     stays stable under a sign-flip Byzantine worker while mean training
@@ -283,7 +266,6 @@ print("OK")
 """, devices=8)
 
 
-@requires_jax_set_mesh
 def test_fsdp_mode_matches_gather_median():
     """param_mode=fsdp (robust reduce-scatter in bwd) produces the exact
     same update as the paper-faithful gather-median, with params/optimizer
@@ -321,7 +303,6 @@ print("OK")
 """)
 
 
-@requires_jax_set_mesh
 def test_bucketed_strategy_in_train_step():
     run_sub(PRELUDE + """
 from repro.configs import get_smoke_config, ParallelConfig
@@ -362,7 +343,7 @@ def test_bucketed_leaf_coalescing_collective_count():
     all_gather pairs (O(#size-bins)), not one pair per leaf — asserted by
     counting collective eqns in the traced jaxpr."""
     run_sub(SMAP + """
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 rng = np.random.default_rng(7)
 # 6 leaves of size 40 (one log2 bin) + 2 leaves of size 300 (another bin)
 shapes = [(40,)] * 6 + [(300,), (30, 10)]
@@ -426,7 +407,7 @@ def test_chunked_agg_single_psum_per_chunk_and_scan():
     chunk count."""
     run_sub(SMAP + """
 g = jnp.asarray(np.random.default_rng(0).standard_normal((8, 100)), jnp.float32)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 
 for method, psums in (("median", 1), ("trimmed_mean", 1)):
     def body(gg, method=method):
@@ -464,7 +445,7 @@ def test_bucketed_coalesced_attack_parity_with_gather():
     leaves into super-buckets must not change the attacked estimator:
     bucketed(leaf) == gather for a multi-leaf tree under sign_flip."""
     run_sub(SMAP + """
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 rng = np.random.default_rng(3)
 shapes = [(11,), (11,), (4, 3), (64,)]
 gs = [jnp.asarray(rng.standard_normal((8,) + s), jnp.float32) for s in shapes]
@@ -495,7 +476,7 @@ def test_psum_agg_plain_mean_baseline():
     psum cannot compute a median; failing loudly keeps the baseline
     honest)."""
     run_sub(SMAP + """
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 rng = np.random.default_rng(7)
 ga = rng.standard_normal((8, 37)).astype(np.float32)
 gb = rng.standard_normal((8, 3, 5)).astype(np.float32)

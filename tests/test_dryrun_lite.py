@@ -8,8 +8,6 @@ import textwrap
 
 import pytest
 
-from conftest import requires_jax_set_mesh
-
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 FAMS = [
@@ -33,7 +31,6 @@ def run_sub(code: str, devices: int = 8) -> str:
 
 
 @pytest.mark.parametrize("arch,fam", FAMS)
-@requires_jax_set_mesh
 def test_train_and_decode_lower_compile(arch, fam):
     run_sub(f"""
 import dataclasses
@@ -66,7 +63,6 @@ print("OK {arch}")
 """)
 
 
-@requires_jax_set_mesh
 def test_multi_pod_mesh_lowering():
     """pod axis shards: 2x2x2 debug multi-pod mesh, robust agg across
     ('pod','data') jointly."""
@@ -125,7 +121,6 @@ print("OK")
 """)
 
 
-@requires_jax_set_mesh
 def test_seq_parallel_lowering():
     run_sub("""
 import jax, jax.numpy as jnp
@@ -149,7 +144,6 @@ print("OK")
 """)
 
 
-@requires_jax_set_mesh
 def test_long_context_decode_lowering():
     """long_500k-style decode for an SSM (native) and dense+swa variant."""
     run_sub("""

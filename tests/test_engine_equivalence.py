@@ -460,9 +460,10 @@ class TestFedEquivalence:
 STRATEGY_PROG = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.robust_gd import make_worker_shards, linreg_loss
+from repro.launch.mesh import make_mesh
 from repro.rounds import LocalUpdateConfig, engine, make_local_update_round
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 kx, kn, kw = jax.random.split(jax.random.PRNGKey(0), 3)
 d, n, m = 6, 8, 8
 x = jax.random.normal(kx, (n*m, d))

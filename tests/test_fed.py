@@ -360,20 +360,16 @@ def test_distributed_chunked_strategy():
     import functools
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    try:
-        shard_map = jax.shard_map
-        kw = {"axis_names": {"data"}, "check_vma": False}
-    except AttributeError:  # jax < 0.5
-        from jax.experimental.shard_map import shard_map
-        kw = {"check_rep": False}
     from repro.core import distributed
     from repro.core.attacks import AttackConfig, apply_gradient_attack
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     g_all = np.random.default_rng(0).standard_normal((8, 37)).astype(np.float32)
 
     def mk(method, attack=None):
-        @functools.partial(shard_map, mesh=mesh, in_specs=P("data"), out_specs=P(), **kw)
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("data"), out_specs=P(),
+                           axis_names={"data"}, check_vma=False)
         def f(g):
             return distributed.robust_chunked_agg(
                 {"w": g[0]}, ("data",), method, beta=0.25, attack=attack,

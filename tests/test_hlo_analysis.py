@@ -4,7 +4,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from conftest import requires_jax_shard_map
 from repro.launch import hlo_analysis, roofline
 
 
@@ -29,14 +28,7 @@ def test_plain_matmul_flops_exact():
 
 def test_xla_cost_analysis_ignores_trip_count():
     """The bug this module exists to fix."""
-    ca2 = _scan_model(2).cost_analysis()
-    if not isinstance(ca2, dict):
-        # probed at runtime (not collection) so only this test pays the
-        # compile: older jax returns a one-element list of dicts here —
-        # the dict indexing below is the newer-jax API
-        pytest.skip("compiled.cost_analysis() returns a list on this jax "
-                    "(dict on newer jax)")
-    f2 = ca2["flops"]
+    f2 = _scan_model(2).cost_analysis()["flops"]
     f8 = _scan_model(8).cost_analysis()["flops"]
     assert f2 == f8  # XLA: body counted once
 
@@ -72,7 +64,6 @@ def test_scan_bytes_not_billed_full_buffer():
     assert per_iter < 8 * slice_bytes  # would be ~L× slice_bytes if mis-billed
 
 
-@requires_jax_shard_map
 def test_collective_bytes_with_trip_count():
     import functools
     import subprocess, sys, os, textwrap
@@ -82,7 +73,8 @@ def test_collective_bytes_with_trip_count():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.launch import hlo_analysis
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("data"),
                            out_specs=P("data"), axis_names={"data"}, check_vma=False)
         def f(x):

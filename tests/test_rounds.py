@@ -28,8 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_jax_set_mesh
-
 from repro.core import theory
 from repro.core.attacks import AttackConfig
 from repro.core.robust_gd import (
@@ -39,6 +37,7 @@ from repro.core.robust_gd import (
     robust_gd,
 )
 from repro.fed.rounds import AttackMixture
+from repro.launch.mesh import make_mesh
 from repro.rounds import (
     CommBudget,
     LocalUpdateConfig,
@@ -227,7 +226,7 @@ class TestOneRoundTheorem7:
                                 attack=AttackConfig("stale", alpha=0.25))
         from repro.rounds import one_round_distributed
 
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         with pytest.raises(ValueError, match="adaptive"):
             one_round_distributed(quadratic_local_solver, shards, mesh,
                                   OneRoundConfig("median"),
@@ -388,12 +387,13 @@ PRELUDE = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.attacks import AttackConfig
 from repro.core.robust_gd import make_worker_shards, linreg_loss
+from repro.launch.mesh import make_mesh
 from repro.rounds import (LocalUpdateConfig, OneRoundConfig,
                           make_local_update_round, local_update_gd,
                           one_round, one_round_distributed,
                           quadratic_local_solver)
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 kx, kn, kw = jax.random.split(jax.random.PRNGKey(0), 3)
 d, n, m = 6, 32, 8
 x = jax.random.normal(kx, (n*m, d))
@@ -464,7 +464,7 @@ print("OK")
         from repro.rounds import make_local_update_round, one_round_distributed
 
         shards, _ = _linreg(0.0, 16, 4, d=4)
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         cfg = LocalUpdateConfig(num_rounds=1)
         with pytest.raises(ValueError, match="omniscient"):
             one_round_distributed(quadratic_local_solver, shards, mesh,
@@ -483,7 +483,6 @@ print("OK")
 # ---------------------------------------------------------------------------
 
 
-@requires_jax_set_mesh
 def test_train_step_one_collective_per_round_hlo():
     """local_steps=4 scans the local updates INSIDE the train step: the
     lowered StableHLO must contain a while loop and exactly the same
@@ -527,7 +526,6 @@ print("OK", c1)
 """)
 
 
-@requires_jax_set_mesh
 def test_train_step_local_rounds_still_learn():
     """local_steps=4 training on the debug mesh still reduces the loss
     (end-to-end: scan + single aggregation + optimizer rescale)."""
@@ -569,7 +567,7 @@ def test_train_step_rejects_local_steps_with_fsdp():
     from repro.optim.optimizers import get_optimizer
 
     cfg = get_smoke_config("llama3.2-3b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     pcfg = ParallelConfig(param_mode="fsdp", local_steps=4)
     with pytest.raises(ValueError, match="local_steps"):
         steps.make_train_step(cfg, pcfg, mesh, get_optimizer("sgd", 1e-2))

@@ -27,6 +27,23 @@ class TestData:
         frac = ((5 * toks + 7) % 97 == labels).mean()
         assert 0.8 < frac <= 1.0
 
+    def test_lm_batch_matches_eager_per_token_loop(self):
+        """The compiled scan draws exactly the stream the per-token eager
+        loop it replaced drew: same keys, same ops, bit for bit."""
+        batch, seq, vocab = 3, 40, 97
+        k1, k2, _ = jax.random.split(KEY, 3)
+        toks = [jax.random.randint(k1, (batch, 1), 0, vocab)[:, 0]]
+        keys = jax.random.split(k2, 2 * seq).reshape(seq, 2, -1)
+        for i in range(seq):
+            nxt = (5 * toks[-1] + 7) % vocab
+            noise = jax.random.randint(keys[i, 0], (batch,), 0, vocab)
+            pick = jax.random.bernoulli(keys[i, 1], 0.9, (batch,))
+            toks.append(jnp.where(pick, nxt, noise))
+        stream = np.asarray(jnp.stack(toks, axis=1))
+        b = lm_batch(KEY, batch, seq, vocab)
+        np.testing.assert_array_equal(np.asarray(b["tokens"]), stream[:, :-1])
+        np.testing.assert_array_equal(np.asarray(b["labels"]), stream[:, 1:])
+
     def test_mnist_analog_separable(self):
         d = mnist_analog(KEY, 2000)
         assert d["x"].shape == (2000, 784)

@@ -3,12 +3,9 @@ and the lowering contract of the donated window step.
 
 The multi-device tests run in subprocesses with
 XLA_FLAGS=--xla_force_host_platform_device_count (the main test process
-keeps the default 1 device per the dry-run contract).  They use the
-version-compat shard_map path (rounds.distributed.shard_map_compat), so
-they run on BOTH jax legs of the CI matrix; the one test that pins the
-newer-jax ``steps.make_train_step`` path is guarded.
+keeps the default 1 device per the dry-run contract).
 
-Pinned here (the ISSUE's acceptance criteria):
+Pinned here:
 
 - same seed => bit-identical final params for device_steps 1 vs 4,
   including under an in-step randomized attack (the per-micro-step
@@ -28,8 +25,6 @@ import sys
 import textwrap
 
 import pytest
-
-from conftest import requires_jax_shard_map
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -101,19 +96,17 @@ def test_ds1_bitwise_equals_handrolled_step_loop():
     python loop over the SAME validated step body (steps.make_step_body)
     wrapped step-by-step — the scan adds nothing to the numerics."""
     run_sub(PRELUDE + """
-from repro.rounds import distributed as rounds_dist
-
 attack = AttackConfig("sign_flip", 0.25)
 opt = get_optimizer("adamw", 1e-2, 0.0, 0.9)
 tcfg = TrainConfig(optimizer="adamw", lr=1e-2, steps=4, device_steps=1)
 r = trainer.train_loop(cfg, pcfg, tcfg, mesh, dcfg=dcfg, attack=attack)
 
 sb = steps.make_step_body(cfg, pcfg, mesh, opt, attack)
-stepped = rounds_dist.shard_map_compat(
-    sb.body, mesh,
-    (sb.pspec, sb.ospec, sb.batch_spec, P(), P()),
-    (sb.pspec, sb.ospec, P()),
-    axis_names=sb.waxes)
+stepped = jax.shard_map(
+    sb.body, mesh=mesh,
+    in_specs=(sb.pspec, sb.ospec, sb.batch_spec, P(), P()),
+    out_specs=(sb.pspec, sb.ospec, P()),
+    axis_names=frozenset(sb.waxes), check_vma=False)
 stepped = jax.jit(stepped)
 state = trainer.init_state(cfg, mesh, opt, seed=0, pcfg=pcfg)
 params, opt_state = state["params"], state["opt_state"]
@@ -128,7 +121,6 @@ print("OK")
 """)
 
 
-@requires_jax_shard_map
 def test_ds1_bitwise_equals_make_train_step():
     """Against the OTHER production path: the newer-jax pinned
     steps.make_train_step (jax.shard_map partial-manual) driven step by
@@ -221,13 +213,12 @@ def test_cli_trains_end_to_end():
 
 
 def test_train_loop_rejects_ragged_windows():
-    import jax
-
     from repro.configs import llama3_2_3b
     from repro.configs.base import ParallelConfig, TrainConfig
     from repro.launch import trainer
+    from repro.launch.mesh import make_debug_mesh
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_debug_mesh(1, 1)
     cfg = llama3_2_3b.smoke_config()
     pcfg = ParallelConfig()
     with pytest.raises(ValueError, match="multiple of device_steps"):
@@ -235,14 +226,13 @@ def test_train_loop_rejects_ragged_windows():
 
 
 def test_make_window_step_rejects_bad_device_steps():
-    import jax
-
     from repro.configs import llama3_2_3b
     from repro.configs.base import ParallelConfig
     from repro.launch import trainer
+    from repro.launch.mesh import make_debug_mesh
     from repro.optim.optimizers import get_optimizer
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_debug_mesh(1, 1)
     with pytest.raises(ValueError, match="device_steps"):
         trainer.make_window_step(llama3_2_3b.smoke_config(), ParallelConfig(),
                                  mesh, get_optimizer("sgd", 1e-2),

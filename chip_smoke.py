@@ -75,22 +75,6 @@ def check(ok: bool, what: str) -> None:
     print(f"  ok: {what}", flush=True)
 
 
-class CompileCounter:
-    """Counts XLA backend compilations (cache hits included)."""
-
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        import jax
-
-        self.n = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, duration, **kwargs):
-        if event == self.EVENT:
-            self.n += 1
-
-
 def run_train(counter, *, layers, strategy, agg, workers, steps, device_steps,
               attack=None, seed=0):
     """One ``train_loop`` run; returns (result, compiles in each window)."""
@@ -338,7 +322,7 @@ def main(argv=None) -> int:
     try:
         import jax
 
-        from repro.launch import runtime
+        from repro.launch import runtime, spans
     except ImportError as e:
         print(f"chip_smoke: cannot import the program: {e}", file=sys.stderr)
         return 2
@@ -356,7 +340,7 @@ def main(argv=None) -> int:
               f"JAX found {len(devices)}", file=sys.stderr)
         return 1
 
-    counter = CompileCounter()
+    counter = spans.CompileCounter()
     t0 = time.perf_counter()
     try:
         if args.chips == 4:

@@ -62,6 +62,7 @@ import jax.numpy as jnp
 from repro.core import aggregators
 from repro.core import attacks as attacks_mod
 from repro.core.attacks import AttackConfig, apply_gradient_attack
+from repro.launch import spans
 
 
 def axis_size(axis_names: Sequence[str]) -> int:
@@ -88,7 +89,8 @@ def _maybe_attack(stacked: jax.Array, attack: Optional[AttackConfig], m: int,
     if attack is None or attack.name == "none" or attack.alpha == 0.0:
         return stacked
     mask = attack.byzantine_mask(m)
-    return apply_gradient_attack(attack, stacked, mask, key=key)
+    with jax.named_scope(spans.ATTACK):
+        return apply_gradient_attack(attack, stacked, mask, key=key)
 
 
 # --------------------------------------------------------------------------
@@ -340,7 +342,8 @@ def robust_reduce_scatter(
     Returns only this worker's aggregated bucket (padded bucket size).
     Used by the robust-FSDP parameter gather's backward pass.
     """
-    out, _ = _robust_scatter_flat(flat, axis_names, method, beta, attack, agg_dtype)
+    with jax.named_scope(spans.AGGREGATE):
+        out, _ = _robust_scatter_flat(flat, axis_names, method, beta, attack, agg_dtype)
     return out
 
 
@@ -440,7 +443,8 @@ def robust_chunked_agg(
         if agg_dtype is not None:
             flat = flat.astype(agg_dtype)
         flat = flat.astype(jnp.float32)
-        flat = _maybe_attack_chunked(flat, attack, axis_names, m, attack_key)
+        with jax.named_scope(spans.ATTACK):
+            flat = _maybe_attack_chunked(flat, attack, axis_names, m, attack_key)
         if method == "mean":
             out = jax.lax.psum(flat, axis_names) / m
             return out.reshape(leaf.shape).astype(leaf.dtype)
@@ -527,7 +531,8 @@ def robust_psum_agg(
         if agg_dtype is not None:
             flat = flat.astype(agg_dtype)
         flat = flat.astype(jnp.float32)
-        flat = _maybe_attack_chunked(flat, attack, axis_names, m, attack_key)
+        with jax.named_scope(spans.ATTACK):
+            flat = _maybe_attack_chunked(flat, attack, axis_names, m, attack_key)
         out = jax.lax.psum(flat, axis_names) / m
         return out.reshape(leaf.shape).astype(leaf.dtype)
 

@@ -17,6 +17,8 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.launch import spans
+
 
 @functools.partial(jax.jit, static_argnames=("batch", "seq", "vocab"))
 def lm_batch(key, batch: int, seq: int, vocab: int) -> Dict[str, jax.Array]:
@@ -27,21 +29,22 @@ def lm_batch(key, batch: int, seq: int, vocab: int) -> Dict[str, jax.Array]:
     per-position loop cost ~10 host dispatches per token, which at seq
     2048 took longer than the train step it fed.
     """
-    k1, k2, k3 = jax.random.split(key, 3)
-    first = jax.random.randint(k1, (batch, 1), 0, vocab)
+    with jax.named_scope(spans.LM_BATCH):
+        k1, k2, k3 = jax.random.split(key, 3)
+        first = jax.random.randint(k1, (batch, 1), 0, vocab)
 
-    def step(tok, ks):
-        knoise, kpick = ks
-        nxt = (5 * tok + 7) % vocab
-        noise = jax.random.randint(knoise, tok.shape, 0, vocab)
-        pick = jax.random.bernoulli(kpick, 0.9, tok.shape)
-        tok = jnp.where(pick, nxt, noise)
-        return tok, tok
+        def step(tok, ks):
+            knoise, kpick = ks
+            nxt = (5 * tok + 7) % vocab
+            noise = jax.random.randint(knoise, tok.shape, 0, vocab)
+            pick = jax.random.bernoulli(kpick, 0.9, tok.shape)
+            tok = jnp.where(pick, nxt, noise)
+            return tok, tok
 
-    keys = jax.random.split(k2, 2 * seq).reshape(seq, 2, -1)
-    _, toks = jax.lax.scan(step, first[:, 0], keys)
-    stream = jnp.concatenate([first, toks.T], axis=1)  # (B, seq+1)
-    return {"tokens": stream[:, :-1], "labels": stream[:, 1:]}
+        keys = jax.random.split(k2, 2 * seq).reshape(seq, 2, -1)
+        _, toks = jax.lax.scan(step, first[:, 0], keys)
+        stream = jnp.concatenate([first, toks.T], axis=1)  # (B, seq+1)
+        return {"tokens": stream[:, :-1], "labels": stream[:, 1:]}
 
 
 def mnist_analog(key, n: int, d: int = 784, num_classes: int = 10,

@@ -28,6 +28,7 @@ from repro.configs.base import ModelConfig, ParallelConfig, ShapeConfig
 from repro.core import distributed
 from repro.core.attacks import AttackConfig
 from repro.launch import mesh as mesh_lib
+from repro.launch import spans
 from repro.rounds import comm
 from repro.rounds import compression as comp_lib
 from repro.rounds import distributed as rounds_dist
@@ -408,28 +409,30 @@ def make_step_body(
                              kv_block=pcfg.attn_chunk)
 
     def _core(params, opt_state, comp, batch, step, atk_base):
-        if tau == 1:
-            loss, grads = jax.value_and_grad(local_loss)(params, batch)
-        else:
-            # communication round: scan tau local SGD steps on this
-            # worker's batch shard and transmit the ACCUMULATED local
-            # gradient — the collective below fires once per round, not
-            # per local step (HLO-asserted in tests/test_rounds.py);
-            # shared scan body: rounds.distributed.scan_local_sgd
-            grads, loss = rounds_dist.scan_local_sgd(
-                lambda p: jax.value_and_grad(local_loss)(p, batch),
-                params, tau, pcfg.local_lr)
+        with jax.named_scope(spans.LOCAL_GRAD):
+            if tau == 1:
+                loss, grads = jax.value_and_grad(local_loss)(params, batch)
+            else:
+                # communication round: scan tau local SGD steps on this
+                # worker's batch shard and transmit the ACCUMULATED local
+                # gradient — the collective below fires once per round, not
+                # per local step (HLO-asserted in tests/test_rounds.py);
+                # shared scan body: rounds.distributed.scan_local_sgd
+                grads, loss = rounds_dist.scan_local_sgd(
+                    lambda p: jax.value_and_grad(local_loss)(p, batch),
+                    params, tau, pcfg.local_lr)
         # step-folded key: randomized attacks draw fresh noise each step
         atk_key = jax.random.fold_in(atk_base, step)
         if fsdp:
             # gradients of sharded leaves arrive already robustly reduced
             # (the gathers' backward IS the robust reduce-scatter); only
             # the few replicated leaves still need cross-worker reduction.
-            agg = jax.tree.map(
-                lambda d, g: g if d >= 0 else distributed.robust_gather_agg(
-                    {"x": g}, waxes, pcfg.agg_method, pcfg.agg_beta, attack,
-                    agg_dtype, attack_key=atk_key)["x"],
-                dims, grads)
+            with jax.named_scope(spans.AGGREGATE):
+                agg = jax.tree.map(
+                    lambda d, g: g if d >= 0 else distributed.robust_gather_agg(
+                        {"x": g}, waxes, pcfg.agg_method, pcfg.agg_beta, attack,
+                        agg_dtype, attack_key=atk_key)["x"],
+                    dims, grads)
         elif ef:
             # error feedback: this worker's residual shard ``comp`` is
             # (1, D); transmit decode(encode(g + e)) and carry the new
@@ -439,32 +442,37 @@ def make_step_body(
                 ckey = jax.random.fold_in(
                     jax.random.fold_in(jax.random.PRNGKey(11), step),
                     distributed.worker_index(waxes))
-            grads, new_res = comp_lib.compress_tree(
-                pcfg.compression, grads, key=ckey, residual=comp[0])
+            with jax.named_scope(spans.CODEC):
+                grads, new_res = comp_lib.compress_tree(
+                    pcfg.compression, grads, key=ckey, residual=comp[0])
             comp = jnp.expand_dims(new_res, 0)
-            agg = rounds_dist.aggregate_by_strategy(
-                grads, waxes, pcfg.agg_strategy, pcfg.agg_method, pcfg.agg_beta,
-                attack, agg_dtype, attack_key=atk_key)
+            with jax.named_scope(spans.AGGREGATE):
+                agg = rounds_dist.aggregate_by_strategy(
+                    grads, waxes, pcfg.agg_strategy, pcfg.agg_method, pcfg.agg_beta,
+                    attack, agg_dtype, attack_key=atk_key)
         else:
-            agg = rounds_dist.aggregate_by_strategy(
-                grads, waxes, pcfg.agg_strategy, pcfg.agg_method, pcfg.agg_beta,
-                attack, agg_dtype, attack_key=atk_key,
-                compression=pcfg.compression,
-                comp_key=jax.random.fold_in(jax.random.PRNGKey(11), step))
+            with jax.named_scope(spans.AGGREGATE):
+                agg = rounds_dist.aggregate_by_strategy(
+                    grads, waxes, pcfg.agg_strategy, pcfg.agg_method, pcfg.agg_beta,
+                    attack, agg_dtype, attack_key=atk_key,
+                    compression=pcfg.compression,
+                    comp_key=jax.random.fold_in(jax.random.PRNGKey(11), step))
         if tau > 1:
             # hand the optimizer the MEAN local gradient so lr semantics
             # match tau=1 (the robust aggregate of Σ_k g_k, rescaled —
             # scaling after aggregation commutes with coordinate-wise
             # aggregators)
             agg = jax.tree.map(lambda g: g / tau, agg)
-        new_params, new_opt = opt.update(agg, opt_state, params, step)
-        sq = sum(jnp.sum(g.astype(jnp.float32) ** 2) for g in jax.tree.leaves(agg))
-        if fsdp:
-            sq = jax.lax.psum(sq, waxes)  # shards are disjoint across workers
-        metrics = {
-            "loss": jax.lax.pmean(loss, waxes),
-            "grad_norm": jnp.sqrt(sq),
-        }
+        with jax.named_scope(spans.UPDATE):
+            new_params, new_opt = opt.update(agg, opt_state, params, step)
+        with jax.named_scope(spans.METRICS):
+            sq = sum(jnp.sum(g.astype(jnp.float32) ** 2) for g in jax.tree.leaves(agg))
+            if fsdp:
+                sq = jax.lax.psum(sq, waxes)  # shards are disjoint across workers
+            metrics = {
+                "loss": jax.lax.pmean(loss, waxes),
+                "grad_norm": jnp.sqrt(sq),
+            }
         return new_params, new_opt, comp, metrics
 
     def body(params, opt_state, batch, step, atk_base):

@@ -134,7 +134,8 @@ def main(argv=None) -> int:
     print(f"done: {result.steps} steps in windows of {result.device_steps}  "
           f"compile {result.compile_s:.2f}s  "
           f"steady {result.steps_per_s:.2f} steps/s  "
-          f"{result.tokens_per_s:.0f} tokens/s")
+          f"{result.tokens_per_s:.0f} tokens/s  "
+          f"recompiles after the first window {result.recompiles}")
     if args.ckpt:
         save_ckpt(args.ckpt, {"params": result.state["params"]}, step=result.steps,
                   extra={"arch": cfg.name, "agg": args.agg,

@@ -43,7 +43,7 @@ from repro.configs.base import ModelConfig, ParallelConfig, ShapeConfig, TrainCo
 from repro.core.attacks import AttackConfig
 from repro.data.pipeline import DataConfig, make_lm_batch
 from repro.launch import mesh as mesh_lib
-from repro.launch import steps
+from repro.launch import spans, steps
 from repro.models import transformer as T
 from repro.optim.optimizers import Optimizer, get_optimizer
 from repro.rounds import compression as comp_lib
@@ -286,6 +286,9 @@ class TrainResult:
     # scheduler interference only ever ADDS time — and is what the
     # throughput benchmark's overhead gate uses.
     window_times_s: List[float] = dataclasses.field(default_factory=list)
+    # backend compilations after the first window: 0 unless a window step
+    # (or anything else the loop runs) recompiled
+    recompiles: int = 0
 
     @property
     def min_step_time_s(self) -> float:
@@ -332,8 +335,10 @@ def train_loop(
         dcfg = DataConfig(kind="lm", vocab=cfg.vocab, seq_len=1024,
                           global_batch=4 * m, num_workers=m, seed=tcfg.seed)
     opt = get_optimizer(tcfg.optimizer, tcfg.lr, tcfg.weight_decay, tcfg.momentum)
-    window = make_window_step(cfg, pcfg, mesh, opt, attack, device_steps=ds)
-    state = init_state(cfg, mesh, opt, seed=tcfg.seed, pcfg=pcfg)
+    with jax.profiler.TraceAnnotation(spans.BUILD_STEP):
+        window = make_window_step(cfg, pcfg, mesh, opt, attack, device_steps=ds)
+    with jax.profiler.TraceAnnotation(spans.INIT_STATE):
+        state = init_state(cfg, mesh, opt, seed=tcfg.seed, pcfg=pcfg)
 
     history: List[Dict[str, float]] = []
     start_w = 0
@@ -342,13 +347,14 @@ def train_loop(
             raise ValueError("resume=True needs ckpt_dir")
         rnd = None if resume is True else int(resume)
         if rnd is not None or round_engine.latest_round(ckpt_dir) is not None:
-            snap, host = round_engine.load_snapshot(
-                ckpt_dir, dict(state, round=jnp.int32(0)), rnd)
-            snap.pop("round")
-            # restored leaves go back to the template's mesh shardings
-            # (the donated window step was compiled against them)
-            state = jax.tree.map(lambda t, v: jax.device_put(v, t.sharding),
-                                 state, snap)
+            with jax.profiler.TraceAnnotation(spans.RESUME):
+                snap, host = round_engine.load_snapshot(
+                    ckpt_dir, dict(state, round=jnp.int32(0)), rnd)
+                snap.pop("round")
+                # restored leaves go back to the template's mesh shardings
+                # (the donated window step was compiled against them)
+                state = jax.tree.map(lambda t, v: jax.device_put(v, t.sharding),
+                                     state, snap)
             history = list(host.get("history", []))
             start_w = int(state["step"]) // ds
     snapshot = {k: float(v) for k, v in state["metrics"].items()}
@@ -356,33 +362,47 @@ def train_loop(
     compile_s = train_s = 0.0
     window_times: List[float] = []
     t_train = time.perf_counter()
-    for w in range(start_w, n_windows):
-        batches = stack_window_batches(dcfg, w * ds, ds, mesh, attack, cfg)
-        t0 = time.perf_counter()
-        state = window(state, batches)
-        if w == start_w:
-            jax.block_until_ready(state["params"])
-            compile_s = time.perf_counter() - t0
-        else:
-            # per-window wall time (syncs at the boundary — the window
-            # interior stays sync-free; this is the timing read, not an
-            # extra one: block + metric read share the same barrier)
-            jax.block_until_ready(state["params"])
-            window_times.append(time.perf_counter() - t0)
-        if w % log_every == 0 or w == n_windows - 1:
-            met = window_metrics(snapshot, state)  # syncs (boundary only)
-            snapshot = met.pop("_snapshot")
-            met["step"] = (w + 1) * ds
-            history.append(met)
-            if on_window is not None:
-                on_window(w, met)
-        if ckpt_every and ckpt_dir and (w + 1) % ckpt_every == 0:
-            round_engine.save_snapshot(
-                ckpt_dir, dict(state, round=state["step"]),
-                host={"history": history})
-        if w == start_w:
-            # restart the clock after the compile+first-execute window
-            t_train = time.perf_counter()
+    compiles_after_first = None
+    # the window span holds the window's staging, dispatch and the wait for
+    # its end; the metric read and checkpoint that follow have spans of
+    # their own, so a trace names the device's idle gaps after the host's
+    # phases: an idle gap under ``train.window`` alone is the wait's tail
+    # (on a TPU v5e the host wakes ~2 ms after the chip finishes)
+    with spans.CompileCounter() as compiles:
+        for w in range(start_w, n_windows):
+            with jax.profiler.StepTraceAnnotation(spans.WINDOW, step_num=w):
+                with jax.profiler.TraceAnnotation(spans.STAGE_BATCHES):
+                    batches = stack_window_batches(dcfg, w * ds, ds, mesh, attack, cfg)
+                t0 = time.perf_counter()
+                with jax.profiler.TraceAnnotation(spans.DISPATCH):
+                    state = window(state, batches)
+                # per-window wall time (syncs at the boundary — the window
+                # interior stays sync-free; this is the timing read, not an
+                # extra one: block + metric read share the same barrier)
+                jax.block_until_ready(state["params"])
+                if w == start_w:
+                    compile_s = time.perf_counter() - t0
+                else:
+                    window_times.append(time.perf_counter() - t0)
+            if w % log_every == 0 or w == n_windows - 1:
+                with jax.profiler.TraceAnnotation(spans.METRIC_READ):
+                    met = window_metrics(snapshot, state)  # syncs (boundary only)
+                snapshot = met.pop("_snapshot")
+                met["step"] = (w + 1) * ds
+                history.append(met)
+                if on_window is not None:
+                    on_window(w, met)
+            if ckpt_every and ckpt_dir and (w + 1) % ckpt_every == 0:
+                with jax.profiler.TraceAnnotation(spans.CHECKPOINT):
+                    round_engine.save_snapshot(
+                        ckpt_dir, dict(state, round=state["step"]),
+                        host={"history": history})
+            if w == start_w:
+                # restart the clock and the compile count after the
+                # compile+first-execute window
+                t_train = time.perf_counter()
+                compiles_after_first = compiles.n
+        recompiles = 0 if compiles_after_first is None else compiles.n - compiles_after_first
     jax.block_until_ready(state["params"])
     train_s = time.perf_counter() - t_train if n_windows - start_w > 1 else 0.0
     steady_steps = max((n_windows - start_w) * ds - ds, 0)
@@ -391,4 +411,5 @@ def train_loop(
     return TrainResult(
         state=state, history=history, steps=tcfg.steps, device_steps=ds,
         compile_s=compile_s, train_s=train_s, steps_per_s=steps_per_s,
-        tokens_per_s=steps_per_s * tokens, window_times_s=window_times)
+        tokens_per_s=steps_per_s * tokens, window_times_s=window_times,
+        recompiles=recompiles)

@@ -17,6 +17,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.launch import spans
+
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
@@ -35,31 +37,34 @@ def moe_ffn(
     e = w_router.shape[1]
     cap = min(s, max(4, _round_up(int(capacity_factor * top_k * s / e), 4)))
 
-    logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)  # (B, S, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_idx = jax.lax.top_k(probs, top_k)  # (B, S, K)
-    top_p = top_p / jnp.maximum(jnp.sum(top_p, axis=-1, keepdims=True), 1e-9)
+    with jax.named_scope(spans.MOE_DISPATCH):
+        logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)  # (B, S, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_idx = jax.lax.top_k(probs, top_k)  # (B, S, K)
+        top_p = top_p / jnp.maximum(jnp.sum(top_p, axis=-1, keepdims=True), 1e-9)
 
-    dispatch = jnp.zeros((b, s, e, cap), jnp.float32)
-    combine = jnp.zeros((b, s, e, cap), jnp.float32)
-    counts = jnp.zeros((b, e), jnp.float32)  # tokens already assigned per expert
-    for slot in range(top_k):
-        oh = jax.nn.one_hot(top_idx[..., slot], e, dtype=jnp.float32)  # (B, S, E)
-        pos = jnp.cumsum(oh, axis=1) - oh + counts[:, None, :]  # (B, S, E)
-        keep = oh * (pos < cap)
-        pos_at = jnp.sum(pos * keep, axis=-1).astype(jnp.int32)  # (B, S)
-        pos_oh = jax.nn.one_hot(pos_at, cap, dtype=jnp.float32)  # (B, S, C)
-        sel = keep[..., None] * pos_oh[..., None, :]  # (B, S, E, C)
-        dispatch = dispatch + sel
-        combine = combine + top_p[..., slot, None, None] * sel
-        counts = counts + jnp.sum(keep, axis=1)
+        dispatch = jnp.zeros((b, s, e, cap), jnp.float32)
+        combine = jnp.zeros((b, s, e, cap), jnp.float32)
+        counts = jnp.zeros((b, e), jnp.float32)  # tokens already assigned per expert
+        for slot in range(top_k):
+            oh = jax.nn.one_hot(top_idx[..., slot], e, dtype=jnp.float32)  # (B, S, E)
+            pos = jnp.cumsum(oh, axis=1) - oh + counts[:, None, :]  # (B, S, E)
+            keep = oh * (pos < cap)
+            pos_at = jnp.sum(pos * keep, axis=-1).astype(jnp.int32)  # (B, S)
+            pos_oh = jax.nn.one_hot(pos_at, cap, dtype=jnp.float32)  # (B, S, C)
+            sel = keep[..., None] * pos_oh[..., None, :]  # (B, S, E, C)
+            dispatch = dispatch + sel
+            combine = combine + top_p[..., slot, None, None] * sel
+            counts = counts + jnp.sum(keep, axis=1)
 
-    xe = jnp.einsum("bsec,bsd->ebcd", dispatch.astype(x.dtype), x)  # (E, B, C, D)
-    h = jnp.einsum("ebcd,edf->ebcf", xe, w_gate)
-    u = jnp.einsum("ebcd,edf->ebcf", xe, w_up)
-    h = jax.nn.silu(h) * u
-    ye = jnp.einsum("ebcf,efd->ebcd", h, w_down)  # (E, B, C, D)
-    y = jnp.einsum("bsec,ebcd->bsd", combine, ye.astype(jnp.float32)).astype(x.dtype)
+        xe = jnp.einsum("bsec,bsd->ebcd", dispatch.astype(x.dtype), x)  # (E, B, C, D)
+    with jax.named_scope(spans.MOE_EXPERTS):
+        h = jnp.einsum("ebcd,edf->ebcf", xe, w_gate)
+        u = jnp.einsum("ebcd,edf->ebcf", xe, w_up)
+        h = jax.nn.silu(h) * u
+        ye = jnp.einsum("ebcf,efd->ebcd", h, w_down)  # (E, B, C, D)
+    with jax.named_scope(spans.MOE_COMBINE):
+        y = jnp.einsum("bsec,ebcd->bsd", combine, ye.astype(jnp.float32)).astype(x.dtype)
 
     # Switch aux loss: E/K · Σ_e (routed fraction_e · mean router prob_e)
     frac = jnp.mean(jnp.sum(dispatch, axis=-1), axis=(0, 1))

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, SSMConfig
+from repro.launch import spans
 from repro.models import attention as attn_lib
 from repro.models import layers as L
 from repro.models import moe as moe_lib
@@ -222,38 +223,40 @@ def _attn_layer_fwd(
     g = h // kv
     if positions is None:
         positions = jnp.arange(s)[None, :]
-    y = L.rms_norm(x, p["ln1"], cfg.norm_eps)
-    q = (y @ p["wq"]).reshape(b, s, kv, g, hd)
-    k = (y @ p["wk"]).reshape(b, s, kv, hd)
-    v = (y @ p["wv"]).reshape(b, s, kv, hd)
-    if cfg.qk_norm:
-        q = L.rms_norm(q, p["q_norm"], cfg.norm_eps)
-        k = L.rms_norm(k, p["k_norm"], cfg.norm_eps)
-    q = L.rope(q.reshape(b, s, kv * g, hd), positions, cfg.rope_theta).reshape(b, s, kv, g, hd)
-    k = L.rope(k, positions, cfg.rope_theta)
-    q = ctx.constrain(q, ("b", None, "m", None, None))
-    k = ctx.constrain(k, ("b", None, "m", None))
-    v = ctx.constrain(v, ("b", None, "m", None))
-    o = attn_lib.attention(q, k, v, causal=causal, window=window, kv_block=kv_block)
-    o = o.reshape(b, s, h * hd) @ p["wo"]
-    x = x + o
-    if enc_out is not None and cross_p is not None:
-        yc = L.rms_norm(x, cross_p["ln1"], cfg.norm_eps)
-        qc = (yc @ cross_p["wq"]).reshape(b, s, kv, g, hd)
-        kc = (enc_out @ cross_p["wk"]).reshape(b, enc_out.shape[1], kv, hd)
-        vc = (enc_out @ cross_p["wv"]).reshape(b, enc_out.shape[1], kv, hd)
-        oc = attn_lib.attention(qc, kc, vc, causal=False, kv_block=kv_block)
-        x = x + oc.reshape(b, s, h * hd) @ cross_p["wo"]
-    y = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-    aux = jnp.float32(0.0)
-    if cfg.moe is not None:
-        f, aux = moe_lib.moe_ffn(y, p["router"], p["we_g"], p["we_u"], p["we_d"], cfg.moe.top_k)
-        f = ctx.constrain(f, ("b", None, None))
-        x = x + f
-    elif cfg.d_ff:
-        hdn = jax.nn.silu(y @ p["wg"]) * (y @ p["wu"])
-        hdn = ctx.constrain(hdn, ("b", None, "m"))
-        x = x + hdn @ p["wd"]
+    with jax.named_scope(spans.ATTN):
+        y = L.rms_norm(x, p["ln1"], cfg.norm_eps)
+        q = (y @ p["wq"]).reshape(b, s, kv, g, hd)
+        k = (y @ p["wk"]).reshape(b, s, kv, hd)
+        v = (y @ p["wv"]).reshape(b, s, kv, hd)
+        if cfg.qk_norm:
+            q = L.rms_norm(q, p["q_norm"], cfg.norm_eps)
+            k = L.rms_norm(k, p["k_norm"], cfg.norm_eps)
+        q = L.rope(q.reshape(b, s, kv * g, hd), positions, cfg.rope_theta).reshape(b, s, kv, g, hd)
+        k = L.rope(k, positions, cfg.rope_theta)
+        q = ctx.constrain(q, ("b", None, "m", None, None))
+        k = ctx.constrain(k, ("b", None, "m", None))
+        v = ctx.constrain(v, ("b", None, "m", None))
+        o = attn_lib.attention(q, k, v, causal=causal, window=window, kv_block=kv_block)
+        o = o.reshape(b, s, h * hd) @ p["wo"]
+        x = x + o
+        if enc_out is not None and cross_p is not None:
+            yc = L.rms_norm(x, cross_p["ln1"], cfg.norm_eps)
+            qc = (yc @ cross_p["wq"]).reshape(b, s, kv, g, hd)
+            kc = (enc_out @ cross_p["wk"]).reshape(b, enc_out.shape[1], kv, hd)
+            vc = (enc_out @ cross_p["wv"]).reshape(b, enc_out.shape[1], kv, hd)
+            oc = attn_lib.attention(qc, kc, vc, causal=False, kv_block=kv_block)
+            x = x + oc.reshape(b, s, h * hd) @ cross_p["wo"]
+    with jax.named_scope(spans.MLP):
+        y = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+        aux = jnp.float32(0.0)
+        if cfg.moe is not None:
+            f, aux = moe_lib.moe_ffn(y, p["router"], p["we_g"], p["we_u"], p["we_d"], cfg.moe.top_k)
+            f = ctx.constrain(f, ("b", None, None))
+            x = x + f
+        elif cfg.d_ff:
+            hdn = jax.nn.silu(y @ p["wg"]) * (y @ p["wu"])
+            hdn = ctx.constrain(hdn, ("b", None, "m"))
+            x = x + hdn @ p["wd"]
     if return_kv:
         return x, aux, (k, v)
     return x, aux
@@ -346,7 +349,8 @@ def forward(
 ) -> Tuple[jax.Array, jax.Array]:
     """Full-sequence forward -> (logits (B, S_text, V), aux_loss)."""
     b, s = tokens.shape
-    x = params["embed"].astype(_dt(cfg))[tokens]
+    with jax.named_scope(spans.EMBED):
+        x = params["embed"].astype(_dt(cfg))[tokens]
     enc_out = None
     n_prefix = 0
     if cfg.frontend == "audio" and cfg.n_enc_layers:
@@ -391,11 +395,12 @@ def forward(
         kw = dict(positions=positions, kv_block=kv_block) if kind == "attn" else {}
         x, a = _layer_fwd(kind, params["tail"][i], x, cfg, ctx, **kw)
         aux_total = aux_total + a
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if n_prefix:
-        x = x[:, n_prefix:]
-    logits = x @ params["lm_head"]
-    logits = ctx.constrain(logits, ("b", None, "m"))
+    with jax.named_scope(spans.HEAD_LOSS):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if n_prefix:
+            x = x[:, n_prefix:]
+        logits = x @ params["lm_head"]
+        logits = ctx.constrain(logits, ("b", None, "m"))
     return logits, aux_total
 
 
@@ -413,8 +418,9 @@ def loss_fn(
         params, batch["tokens"], cfg, ctx, frontend=batch.get("frontend"),
         remat=remat, kv_block=kv_block, block_provider=block_provider,
     )
-    loss = L.cross_entropy(logits, batch["labels"], batch.get("mask"))
-    return loss + aux_weight * aux
+    with jax.named_scope(spans.HEAD_LOSS):
+        loss = L.cross_entropy(logits, batch["labels"], batch.get("mask"))
+        return loss + aux_weight * aux
 
 
 # ---------------------------------------------------------------------------

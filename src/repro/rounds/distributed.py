@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import distributed
+from repro.launch import spans
 from repro.rounds import comm
 from repro.rounds import compression as comp_lib
 from repro.rounds.one_round import OneRoundConfig
@@ -77,7 +78,8 @@ def aggregate_by_strategy(
             key = jax.random.fold_in(base, distributed.worker_index(axis_names))
         elif cspec.shared_key:  # one public per-round map for ALL workers
             key = base
-        g, _ = comp_lib.compress_tree(compression, g, key=key)
+        with jax.named_scope(spans.CODEC):
+            g, _ = comp_lib.compress_tree(compression, g, key=key)
     if strategy == "gather":
         return distributed.robust_gather_agg(
             g, axis_names, method, beta, attack, agg_dtype, attack_key=attack_key)

@@ -14,15 +14,17 @@ One chip:
 2. train: ``launch.trainer.train_loop`` (what ``python -m
    repro.launch.train`` calls) on h2o-danube-1.8b at its published widths
    with only ``n_layers`` cut, seq 2048, global batch 4, bucketed
-   median, AdamW, 4 steps in windows of 2 on a 1x1 mesh.  Loss and |g|
+   median, AdamW, 4 steps in windows of 2 on a 1x1 mesh, attention
+   through the fused kernel (``kernels/attention.py``).  Loss and |g|
    must be finite and the second window must compile nothing.  With one
    worker the median is that worker's gradient, so a psum/mean run from
    the same seed must reach the same parameters;
 3. kernels: ``kernels.ops.robust_aggregate(backend="pallas")`` (median,
    trimmed mean) against ``kernels/ref.py``, and one ``repro.fed.run``
    round aggregate (approx_median, default sketch settings) on the
-   Pallas backend against the XLA one.  Every kernel must have been
-   traced for Mosaic and never for interpret mode.
+   Pallas backend against the XLA one.  Every kernel, the train
+   phase's attention among them, must have been traced for Mosaic and
+   never for interpret mode.
 
 ``--chips 4``: a 4x1 mesh (m = 4 workers) at the one-chip phase's
 per-device batch: bucketed median under ALIE, the psum/mean baseline,
@@ -89,7 +91,7 @@ def run_train(counter, *, layers, strategy, agg, workers, steps, device_steps,
 
     cfg = dataclasses.replace(get_config(ARCH), n_layers=layers)
     mesh = make_debug_mesh(workers, 1)
-    # the train CLI's defaults: remat on, plain attention
+    # the train CLI's defaults: remat on, no XLA attention chunking
     pcfg = ParallelConfig(agg_method=agg, agg_strategy=strategy, remat=True,
                           attn_chunk=0)
     tcfg = TrainConfig(optimizer="adamw", lr=LR, steps=steps, seed=seed,
@@ -280,8 +282,9 @@ def phase_kernels(seed):
     # the 1e-5 relative slack absorbs the rounding of the bin centres
     check(bool(np.all(diff <= width * (1 + 1e-5))),
           "pallas and xla sketch medians agree within one bin width")
-    check_compiled_kernels(["median_pallas", "trimmed_mean_pallas",
-                            "minmax_pallas", "histogram_pallas"])
+    check_compiled_kernels(["splash_attention", "median_pallas",
+                            "trimmed_mean_pallas", "minmax_pallas",
+                            "histogram_pallas"])
 
 
 def phase_four_chips(counter, seed):

@@ -103,7 +103,10 @@ class ParallelConfig:
     agg_strategy: str = "gather"  # gather|bucketed|hierarchical|chunked|psum (paper-faithful default; psum = plain DP mean, no robustness)
     param_mode: str = "replicated"  # replicated|fsdp (fsdp = robust reduce-scatter in bwd)
     remat: bool = True
-    attn_chunk: int = 1024  # kv-block size for chunked attention (0 = plain)
+    # kv-block size of the XLA attention paths (0 = no XLA chunking: plain);
+    # on a TPU, full-sequence causal self-attention takes the fused kernel
+    # (kernels/attention.py) whatever this is
+    attn_chunk: int = 1024
     agg_dtype: str = ""  # '' = aggregate in gradient dtype
     seq_parallel: bool = False  # sequence parallelism between layers
     # communication rounds (repro.rounds): τ local SGD steps between

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels for the paper's aggregation hot-spot.
+"""Pallas TPU kernels: the paper's aggregation hot-spot, and the model's attention.
 
 - selection_network.py: pruned compare-exchange DAG generator (Batcher
   odd-even mergesort + dead-wire elimination for requested rank sets) —
@@ -9,10 +9,13 @@
 - histogram_agg.py: streaming two-pass histogram sketch kernels
   (min/max + bin counts/sums) for federated-scale m, plus the pure-jnp
   CDF-inversion helpers shared by fed.streaming and core.distributed
+- attention.py: the train and prefill paths' fused causal attention
+  (jax's Pallas TPU splash-attention kernel on the model's grouped GQA
+  layout); ``models.attention.attention`` takes it on a TPU
 - ops.py: jit'd dispatch wrappers (pallas on TPU, network/XLA on CPU)
 - mode.py: where the kernels run (Mosaic on a TPU, interpreted
   elsewhere) and a per-kernel record of how each was traced
 - ref.py: pure-jnp jnp.sort oracle used by the allclose tests
 """
 from repro.kernels import (  # noqa: F401
-    histogram_agg, mode, ops, ref, robust_agg, selection_network)
+    attention, histogram_agg, mode, ops, ref, robust_agg, selection_network)

@@ -74,7 +74,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--optimizer", default="adamw")
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--attn-chunk", type=int, default=0, help="0 = plain attention")
+    ap.add_argument("--attn-chunk", type=int, default=0,
+                    help="kv block of XLA attention, 0 = plain (a TPU takes "
+                         "the fused kernel for causal self-attention)")
     ap.add_argument("--log-every", type=int, default=1, help="in windows")
     ap.add_argument("--ckpt", default=None,
                     help="save a final params checkpoint here on exit")

@@ -7,15 +7,23 @@ materialising KV repeated to H heads.
 ``chunked_attention`` scans over KV blocks with an online softmax so no
 (S, S) buffer exists; the scan body is remat'd (jax.checkpoint) so the
 backward pass recomputes per-block probabilities instead of storing them —
-the pure-JAX analogue of a flash kernel, chosen because this repo's
-perf-critical Pallas budget goes to the paper's own hot-spot (robust
-aggregation) and XLA:TPU already pipelines this scan well.
+the pure-JAX analogue of a flash kernel.
+
+``attention`` takes the fused Pallas kernel (``kernels/attention.py``)
+on a TPU wherever the input allows it: full-sequence causal
+self-attention at a length the kernel tiles, in a trace the compiler
+partitions over one device (a Mosaic kernel cannot be partitioned).
+Everywhere else (the CPU, non-causal encoder and cross attention, offset
+queries, ragged lengths, heads or batch split by the compiler) it keeps
+the XLA paths, plain or chunked by ``kv_block``.
 """
 from __future__ import annotations
 
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import attention as fused
 
 NEG_INF = -1e30
 
@@ -101,10 +109,27 @@ def chunked_attention(
     return out.transpose(0, 3, 1, 2, 4).astype(q.dtype)  # (B, Sq, KV, G, hd)
 
 
+def fused_kernel_applies(sq: int, sk: int, causal: bool, q_offset: int,
+                         shards: int, backend: str) -> bool:
+    """Whether ``attention`` takes the fused kernel: on a TPU, for causal
+    self-attention over the whole sequence (no query offset, Sq == Sk) at
+    a length the kernel tiles, when the compiler partitions the trace over
+    no more than one device (``shards``)."""
+    return (backend == "tpu" and causal and q_offset == 0 and sq == sk
+            and sq % fused.LANES == 0 and shards <= 1)
+
+
 def attention(
-    q, k, v, causal: bool = True, window: int = 0, q_offset: int = 0, kv_block: int = 1024
+    q, k, v, causal: bool = True, window: int = 0, q_offset: int = 0,
+    kv_block: int = 1024, shards: int = 1,
 ):
-    """Dispatch: plain for short sequences, chunked otherwise."""
+    """Dispatch: the fused kernel where it applies (see
+    ``fused_kernel_applies``; ``shards`` is the number of devices the
+    compiler partitions the trace over, ``ShardCtx.auto_shards``), else
+    plain for short sequences (or ``kv_block`` 0) and chunked otherwise."""
+    if fused_kernel_applies(q.shape[1], k.shape[1], causal, q_offset, shards,
+                            jax.default_backend()):
+        return fused.causal_attention(q, k, v, window)
     if kv_block == 0 or k.shape[1] <= kv_block:
         return plain_attention(q, k, v, causal, window, q_offset)
     return chunked_attention(q, k, v, causal, window, q_offset, kv_block)

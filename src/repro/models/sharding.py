@@ -16,6 +16,7 @@ the hot activation paths).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -39,6 +40,13 @@ class ShardCtx:
         for a in axes:
             s *= self.mesh_shape.get(a, 1)
         return s
+
+    def auto_shards(self) -> int:
+        """Devices the compiler partitions the current trace over: the
+        mesh's axes less those a surrounding ``shard_map`` made manual (in
+        the train step, the worker axes)."""
+        manual = jax.sharding.get_abstract_mesh().manual_axes
+        return math.prod(n for a, n in self.mesh_shape.items() if a not in manual)
 
     def _ok(self, d: int, axes: Tuple[str, ...]) -> bool:
         """Shard dim d over axes if divisible, or unevenly (GSPMD pads) when

@@ -236,7 +236,8 @@ def _attn_layer_fwd(
         q = ctx.constrain(q, ("b", None, "m", None, None))
         k = ctx.constrain(k, ("b", None, "m", None))
         v = ctx.constrain(v, ("b", None, "m", None))
-        o = attn_lib.attention(q, k, v, causal=causal, window=window, kv_block=kv_block)
+        o = attn_lib.attention(q, k, v, causal=causal, window=window, kv_block=kv_block,
+                               shards=ctx.auto_shards())
         o = o.reshape(b, s, h * hd) @ p["wo"]
         x = x + o
         if enc_out is not None and cross_p is not None:

@@ -116,3 +116,63 @@ def test_bucketed_agg_of_published_width_leaf_compiles_on_four_chips(topo):
     shapes = re.findall(r"= (\w+\[[\d,]+\])\S* all-to-all\(", text)
     assert shapes == [f"bf16[{m},{MLP[0] // m},{MLP[1]}]"], shapes
     assert elapsed < 20.0, f"bucketed compile took {elapsed:.1f} s"
+
+
+# the train cell's attention at h2o-danube-1.8b's widths
+ATTN = dict(b=4, s=2048, kv=8, g=4, hd=80)
+
+
+def test_fused_attention_compiles_forward_and_backward(one_chip):
+    """Mosaic takes the splash kernel at head_dim 80: the forward kernel
+    and the fused backward kernel are both in the compiled program."""
+    from repro.kernels import attention as fused
+
+    b, s, kv, g, hd = (ATTN[k] for k in ("b", "s", "kv", "g", "hd"))
+    q = jax.ShapeDtypeStruct((b, s, kv, g, hd), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, s, kv, hd), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        o = fused.causal_attention(q, k, v, interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, k, k)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") >= 2
+
+
+def test_fused_attention_cuts_the_window_steps_temporaries(topo, monkeypatch):
+    """The train cell's window step (danube widths, seq 2048, batch 4,
+    remat, plain XLA attention below it) at one layer: with the kernel it
+    needs less than half the temporaries, as the (S, S) f32 scores are
+    gone.  The step asks ``jax.default_backend()`` where it runs, which
+    here says the CPU; the kernel's run is steered to the TPU's branch."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.configs.base import ParallelConfig, ShapeConfig
+    from repro.kernels import mode
+    from repro.launch import trainer
+    from repro.optim.optimizers import get_optimizer
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    cfg = dataclasses.replace(get_config("h2o-danube-1.8b"), n_layers=1)
+    pcfg = ParallelConfig(agg_method="median", agg_strategy="bucketed",
+                          remat=True, attn_chunk=0)
+    opt = get_optimizer("adamw", 1e-3)
+    shape = ShapeConfig("cell", ATTN["s"], ATTN["b"], "train")
+
+    def temporaries():
+        window = trainer.make_window_step(cfg, pcfg, mesh, opt, device_steps=2)
+        compiled = window.lower(
+            trainer.abstract_state(cfg, mesh, opt, pcfg),
+            trainer.abstract_window_batches(cfg, shape, mesh, 2)).compile()
+        return (compiled.memory_analysis().temp_size_in_bytes,
+                "tpu_custom_call" in compiled.as_text())
+
+    xla, xla_kernel = temporaries()
+    before = mode.TRACES[("splash_attention", False)]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fused_bytes, has_kernel = temporaries()
+    assert not xla_kernel and has_kernel
+    assert mode.TRACES[("splash_attention", False)] > before
+    assert fused_bytes < xla / 2, (fused_bytes, xla)

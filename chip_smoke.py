@@ -19,12 +19,19 @@ One chip:
    must be finite and the second window must compile nothing.  With one
    worker the median is that worker's gradient, so a psum/mean run from
    the same seed must reach the same parameters;
-3. kernels: ``kernels.ops.robust_aggregate(backend="pallas")`` (median,
+3. sparse experts: ``train_loop`` on granite-3.0-1b-a400m at its
+   published widths and settings (dropless top-8 of 32 experts, tied
+   head, the four multipliers) with ``n_layers`` cut to 2, seq 4096,
+   global batch 2, bucketed median, 4 steps in windows of 2: finite loss,
+   |g| and expert load, no compilation after the first window; then the
+   grouped-matmul kernel against ``jax.lax.ragged_dot`` at the train
+   cell's shapes, forward and backward;
+4. kernels: ``kernels.ops.robust_aggregate(backend="pallas")`` (median,
    trimmed mean) against ``kernels/ref.py``, and one ``repro.fed.run``
    round aggregate (approx_median, default sketch settings) on the
    Pallas backend against the XLA one.  Every kernel, the train
-   phase's attention among them, must have been traced for Mosaic and
-   never for interpret mode.
+   phases' attention and grouped products among them, must have been
+   traced for Mosaic and never for interpret mode.
 
 ``--chips 4``: a 4x1 mesh (m = 4 workers) at the one-chip phase's
 per-device batch: bucketed median under ALIE, the psum/mean baseline,
@@ -47,6 +54,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 
 ARCH = "h2o-danube-1.8b"
+MOE_ARCH, MOE_LAYERS, MOE_SEQ, MOE_BATCH = "granite-moe-1b-a400m", 2, 4096, 2
 PUBLISHED_LAYERS = 24
 # The window step at 8 layers needs 7.20 GB of arguments (bf16 params +
 # f32 AdamW moments, donated) and 8.44 GB of temporaries on one v5e chip
@@ -78,7 +86,7 @@ def check(ok: bool, what: str) -> None:
 
 
 def run_train(counter, *, layers, strategy, agg, workers, steps, device_steps,
-              attack=None, seed=0):
+              attack=None, seed=0, arch=ARCH, seq=SEQ, batch=BATCH_PER_DEVICE):
     """One ``train_loop`` run; returns (result, compiles in each window)."""
     import jax
 
@@ -89,15 +97,15 @@ def run_train(counter, *, layers, strategy, agg, workers, steps, device_steps,
     from repro.launch import trainer
     from repro.launch.mesh import make_debug_mesh
 
-    cfg = dataclasses.replace(get_config(ARCH), n_layers=layers)
+    cfg = dataclasses.replace(get_config(arch), n_layers=layers)
     mesh = make_debug_mesh(workers, 1)
     # the train CLI's defaults: remat on, no XLA attention chunking
     pcfg = ParallelConfig(agg_method=agg, agg_strategy=strategy, remat=True,
                           attn_chunk=0)
     tcfg = TrainConfig(optimizer="adamw", lr=LR, steps=steps, seed=seed,
                        device_steps=device_steps)
-    dcfg = DataConfig(kind="lm", vocab=cfg.vocab, seq_len=SEQ,
-                      global_batch=BATCH_PER_DEVICE * workers,
+    dcfg = DataConfig(kind="lm", vocab=cfg.vocab, seq_len=seq,
+                      global_batch=batch * workers,
                       num_workers=workers, seed=seed)
     attack = attack or AttackConfig("none", 0.0)
     per_window = []
@@ -106,12 +114,13 @@ def run_train(counter, *, layers, strategy, agg, workers, steps, device_steps,
     def on_window(w, met):
         per_window.append(counter.n - start[0])
         start[0] = counter.n
+        load = f"  expert load {met['expert_load']:.4f}" if "expert_load" in met else ""
         print(f"    window {w}: step {met['step']}  loss {met['loss']:.6f}  "
-              f"|g| {met['grad_norm']:.6f}", flush=True)
+              f"|g| {met['grad_norm']:.6f}{load}", flush=True)
 
-    print(f"  {strategy}/{agg} on {workers} worker(s), attack "
+    print(f"  {arch}: {strategy}/{agg} on {workers} worker(s), attack "
           f"{attack.name}:{attack.alpha}, {layers}/{PUBLISHED_LAYERS} layers, "
-          f"seq {SEQ}, global batch {dcfg.global_batch}, {steps} steps in "
+          f"seq {seq}, global batch {dcfg.global_batch}, {steps} steps in "
           f"windows of {device_steps}", flush=True)
     result = trainer.train_loop(cfg, pcfg, tcfg, mesh, dcfg=dcfg,
                                 attack=attack, on_window=on_window)
@@ -129,9 +138,9 @@ def check_train(result, per_window, steps, device_steps):
     check(len(result.history) == steps // device_steps,
           f"{len(result.history)} windows logged")
     for h in result.history:
-        check(math.isfinite(h["loss"]) and math.isfinite(h["grad_norm"]),
+        check(all(math.isfinite(v) for v in h.values()),
               f"finite loss {h['loss']:.6f} and |g| {h['grad_norm']:.6f} "
-              f"at step {h['step']}")
+              f"(and expert load, if any) at step {h['step']}")
     check(all(n == 0 for n in per_window[1:]),
           f"no compilation after the first window (compiles per window "
           f"{per_window})")
@@ -197,6 +206,51 @@ def phase_train_one_chip(counter, seed):
     check_train(base, win, STEPS, DEVICE_STEPS)
     params_agree(robust_params, host_params(base), STEPS,
                  "m=1 bucketed/median vs psum/mean")
+
+
+def phase_moe(counter, seed):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.kernels import grouped_matmul as G
+
+    print(f"== sparse experts: {MOE_ARCH} at published widths, n_layers "
+          f"{MOE_LAYERS} of {PUBLISHED_LAYERS}, seq {MOE_SEQ}, 1x1 mesh", flush=True)
+    result, win = run_train(counter, layers=MOE_LAYERS, strategy="bucketed",
+                            agg="median", workers=1, steps=STEPS,
+                            device_steps=DEVICE_STEPS, seed=seed, arch=MOE_ARCH,
+                            seq=MOE_SEQ, batch=MOE_BATCH)
+    check_train(result, win, STEPS, DEVICE_STEPS)
+    result.state.clear()
+
+    # the train cell's grouped product: 8192 tokens x 8 slots over 32
+    # experts of 1024 x 512, rows routed by a random top-8
+    t, k, d, f, e = MOE_SEQ * MOE_BATCH, 8, 1024, 512, 32
+    print(f"== grouped matmul: ({t * k}, {d}) x ({e}, {d}, {f}) bf16, kernel "
+          f"vs ragged_dot, forward and backward", flush=True)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    idx = jax.lax.top_k(jax.random.normal(ks[0], (t, e)), k)[1].reshape(-1)
+    sizes = jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.int32), axis=0)
+    lhs = jax.random.normal(ks[1], (t * k, d), jnp.bfloat16)
+    rhs = (0.02 * jax.random.normal(ks[2], (e, d, f))).astype(jnp.bfloat16)
+    cot = jax.random.normal(ks[3], (t * k, f), jnp.bfloat16)
+
+    def vjp(product, lhs, rhs, sizes, cot):
+        out, back = jax.vjp(lambda a, b: product(a, b, sizes), lhs, rhs)
+        return (out,) + back(cot)
+
+    args = (lhs, rhs, sizes, cot)
+    got = jax.jit(functools.partial(vjp, G.gmm))(*args)
+    want = jax.jit(functools.partial(vjp, G.ragged))(*args)
+    for g, w, what in zip(got, want, ("output", "rows' gradient", "matrices' gradient")):
+        g = np.asarray(g, np.float32)
+        w = np.asarray(w, np.float32)
+        rel = float(np.linalg.norm(g - w) / np.linalg.norm(w))
+        print(f"    {what}: relative L2 difference {rel:.3e}", flush=True)
+        # both accumulate bf16 products in f32 and round the result to
+        # bf16 once: they differ by the summation order, far under 1e-2
+        check(rel <= 1e-2, f"grouped matmul {what} agrees with ragged_dot")
 
 
 def check_compiled_kernels(names) -> None:
@@ -282,7 +336,7 @@ def phase_kernels(seed):
     # the 1e-5 relative slack absorbs the rounding of the bin centres
     check(bool(np.all(diff <= width * (1 + 1e-5))),
           "pallas and xla sketch medians agree within one bin width")
-    check_compiled_kernels(["splash_attention", "median_pallas",
+    check_compiled_kernels(["splash_attention", "grouped_matmul", "median_pallas",
                             "trimmed_mean_pallas", "minmax_pallas",
                             "histogram_pallas"])
 
@@ -350,6 +404,7 @@ def main(argv=None) -> int:
             phase_four_chips(counter, args.seed)
         else:
             phase_train_one_chip(counter, args.seed)
+            phase_moe(counter, args.seed)
             phase_kernels(args.seed)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)

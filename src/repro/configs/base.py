@@ -55,6 +55,12 @@ class ModelConfig:
     # modality frontend stub
     frontend: str = "none"  # none|audio|vision
     n_frontend_tokens: int = 0  # 1500 audio frames / 256 vision patches
+    # residual and logit path (Granite); the defaults leave a model as is
+    embedding_multiplier: float = 1.0  # token embeddings times this
+    attention_multiplier: float = 0.0  # softmax scale; 0 -> 1/sqrt(hd)
+    residual_multiplier: float = 1.0  # each block's branch times this
+    logits_scaling: float = 1.0  # logits divided by this
+    tie_embeddings: bool = False  # one (D, V) matrix: the head, whose columns embed
     # numerics
     dtype: str = "bfloat16"
     # sub-quadratic variant used only for the long_500k decode shape on

@@ -12,10 +12,13 @@
 - attention.py: the train and prefill paths' fused causal attention
   (jax's Pallas TPU splash-attention kernel on the model's grouped GQA
   layout); ``models.attention.attention`` takes it on a TPU
+- grouped_matmul.py: the dropless expert layer's grouped products (jax's
+  Pallas TPU megablox kernels); ``models.moe`` takes them on a TPU
 - ops.py: jit'd dispatch wrappers (pallas on TPU, network/XLA on CPU)
 - mode.py: where the kernels run (Mosaic on a TPU, interpreted
   elsewhere) and a per-kernel record of how each was traced
 - ref.py: pure-jnp jnp.sort oracle used by the allclose tests
 """
 from repro.kernels import (  # noqa: F401
-    attention, histogram_agg, mode, ops, ref, robust_agg, selection_network)
+    attention, grouped_matmul, histogram_agg, mode, ops, ref, robust_agg,
+    selection_network)

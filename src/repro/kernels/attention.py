@@ -23,7 +23,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 from jax.experimental.pallas.ops.tpu.splash_attention import (
     splash_attention_kernel as splash,
     splash_attention_mask as splash_mask,
@@ -73,39 +72,30 @@ def make_kernel(heads: int, s: int, window: int, interpret: bool):
             interpret=interpret)
 
 
-def _manual(f):
-    """``f`` made manual over the mesh axes its context leaves to the
-    compiler, which cannot partition a Mosaic kernel: inside a
-    ``shard_map`` over the worker axes, the model axis.  The caller
-    guarantees those axes split none of the operands."""
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty or not mesh.manual_axes or not mesh.auto_axes:
-        return f
-    return jax.shard_map(f, in_specs=P(), out_specs=P(),
-                         axis_names=frozenset(mesh.auto_axes), check_vma=False)
-
-
 def causal_attention(
     q: jax.Array,  # (B, S, KV, G, hd)
     k: jax.Array,  # (B, S, KV, hd)
     v: jax.Array,  # (B, S, KV, hd)
     window: int = 0,
     interpret: Optional[bool] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Causal (optionally sliding-window) self-attention through the fused
     kernel; S must be a multiple of ``LANES``.
 
-    q is scaled by 1/sqrt(hd) in f32 and cast once to the operand dtype,
-    q's own (the model's compute dtype: bf16 at the published configs).
+    q is scaled by ``scale`` (default 1/sqrt(hd)) in f32 and cast once to
+    the operand dtype, q's own (the model's compute dtype: bf16 at the
+    published configs).
     """
     interpret = mode.resolve("splash_attention", interpret)
     b, s, kv, g, hd = q.shape
     dtype = q.dtype
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     qh = (q.astype(jnp.float32) * scale).astype(dtype)
     qh = qh.reshape(b, s, kv * g, hd).transpose(0, 2, 1, 3)  # (B, H, S, hd)
     kh = k.astype(dtype).transpose(0, 2, 1, 3)  # (B, KV, S, hd)
     vh = v.astype(dtype).transpose(0, 2, 1, 3)
     kernel = make_kernel(kv * g, s, window if 0 < window < s else 0, interpret)
-    o = _manual(jax.vmap(kernel))(qh, kh, vh)  # (B, H, S, hd)
+    o = mode.manual(jax.vmap(kernel))(qh, kh, vh)  # (B, H, S, hd)
     return o.transpose(0, 2, 1, 3).reshape(b, s, kv, g, hd).astype(dtype)

@@ -16,6 +16,7 @@ import collections
 from typing import Optional
 
 import jax
+from jax.sharding import PartitionSpec as P
 
 TRACES: collections.Counter = collections.Counter()
 
@@ -27,3 +28,15 @@ def resolve(name: str, interpret: Optional[bool]) -> bool:
         interpret = jax.default_backend() != "tpu"
     TRACES[(name, bool(interpret))] += 1
     return bool(interpret)
+
+
+def manual(f):
+    """``f`` made manual over the mesh axes its context leaves to the
+    compiler, which cannot partition a Mosaic kernel: inside a
+    ``shard_map`` over the worker axes, the model axis.  The caller
+    guarantees those axes split none of the operands."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not mesh.manual_axes or not mesh.auto_axes:
+        return f
+    return jax.shard_map(f, in_specs=P(), out_specs=P(),
+                         axis_names=frozenset(mesh.auto_axes), check_vma=False)

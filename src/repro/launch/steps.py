@@ -411,15 +411,16 @@ def make_step_body(
     def _core(params, opt_state, comp, batch, step, atk_base):
         with jax.named_scope(spans.LOCAL_GRAD):
             if tau == 1:
-                loss, grads = jax.value_and_grad(local_loss)(params, batch)
+                (loss, stats), grads = jax.value_and_grad(local_loss, has_aux=True)(
+                    params, batch)
             else:
                 # communication round: scan tau local SGD steps on this
                 # worker's batch shard and transmit the ACCUMULATED local
                 # gradient — the collective below fires once per round, not
                 # per local step (HLO-asserted in tests/test_rounds.py);
                 # shared scan body: rounds.distributed.scan_local_sgd
-                grads, loss = rounds_dist.scan_local_sgd(
-                    lambda p: jax.value_and_grad(local_loss)(p, batch),
+                grads, (loss, stats) = rounds_dist.scan_local_sgd(
+                    lambda p: jax.value_and_grad(local_loss, has_aux=True)(p, batch),
                     params, tau, pcfg.local_lr)
         # step-folded key: randomized attacks draw fresh noise each step
         atk_key = jax.random.fold_in(atk_base, step)
@@ -472,6 +473,8 @@ def make_step_body(
             metrics = {
                 "loss": jax.lax.pmean(loss, waxes),
                 "grad_norm": jnp.sqrt(sq),
+                # the model's statistics (a model with experts: its load)
+                **{k: jax.lax.pmean(v, waxes) for k, v in stats.items()},
             }
         return new_params, new_opt, comp, metrics
 

@@ -125,8 +125,9 @@ def main(argv=None) -> int:
                       seed=args.seed)
 
     def on_window(w, met):
+        load = f"  expert load {met['expert_load']:.3f}" if "expert_load" in met else ""
         print(f"step {met['step']:5d}  loss {met['loss']:.4f}  "
-              f"|g| {met['grad_norm']:.3f}")
+              f"|g| {met['grad_norm']:.3f}{load}")
 
     result = trainer.train_loop(cfg, pcfg, tcfg, mesh, dcfg=dcfg, attack=attack,
                                 log_every=args.log_every, on_window=on_window,

@@ -70,7 +70,7 @@ def init_state(cfg: ModelConfig, mesh, opt: Optimizer, seed: int = 0,
                  if pcfg is not None else ()),
         "step": jnp.int32(0),
         "key": jax.random.PRNGKey(seed),
-        "metrics": zero_metrics(),
+        "metrics": zero_metrics(cfg),
     }
     return jax.device_put(state, state_shardings(cfg, mesh, opt, pcfg))
 
@@ -84,24 +84,31 @@ def state_shardings(cfg: ModelConfig, mesh, opt: Optimizer,
                         abstract_state(cfg, mesh, opt, pcfg))
 
 
-def zero_metrics() -> Dict[str, jax.Array]:
-    return {"loss_sum": jnp.float32(0.0),
-            "grad_norm_sum": jnp.float32(0.0),
-            "micro_steps": jnp.int32(0)}
+SUMMED = ("loss", "grad_norm", "expert_load")  # step metrics the carry sums
+
+
+def zero_metrics(cfg: ModelConfig) -> Dict[str, jax.Array]:
+    """The carry's running sums: loss and grad norm, and for a model with
+    experts its expert load (the largest layer's largest expert's routed
+    rows over the mean)."""
+    met = {"loss_sum": jnp.float32(0.0),
+           "grad_norm_sum": jnp.float32(0.0),
+           "micro_steps": jnp.int32(0)}
+    if cfg.moe is not None:
+        met["expert_load_sum"] = jnp.float32(0.0)
+    return met
 
 
 def window_metrics(before: Dict[str, float], state: Dict[str, Any]) -> Dict[str, float]:
     """Difference the carry's running metric sums against a snapshot taken
-    at the previous window boundary → this window's mean loss/grad-norm.
-    The ONLY host→device syncs of the loop happen here."""
+    at the previous window boundary → this window's mean of each summed
+    metric (loss, grad_norm, expert_load).  The ONLY host→device syncs of
+    the loop happen here."""
     after = {k: float(state["metrics"][k]) for k in state["metrics"]}
     n = after["micro_steps"] - before["micro_steps"]
-    return {
-        "loss": (after["loss_sum"] - before["loss_sum"]) / max(n, 1),
-        "grad_norm": (after["grad_norm_sum"] - before["grad_norm_sum"]) / max(n, 1),
-        "micro_steps": n,
-        "_snapshot": after,
-    }
+    out = {k[:-len("_sum")]: (after[k] - before[k]) / max(n, 1)
+           for k in after if k.endswith("_sum")}
+    return dict(out, micro_steps=n, _snapshot=after)
 
 
 # ---------------------------------------------------------------------------
@@ -151,12 +158,10 @@ def make_window_step(
             else:
                 params, opt_state, m = sb.body(
                     params, opt_state, batch, step, atk_base)
-            met = {
-                "loss_sum": met["loss_sum"] + m["loss"].astype(jnp.float32),
-                "grad_norm_sum": met["grad_norm_sum"]
-                                 + m["grad_norm"].astype(jnp.float32),
-                "micro_steps": met["micro_steps"] + jnp.int32(1),
-            }
+            met = dict(
+                {f"{k}_sum": met[f"{k}_sum"] + m[k].astype(jnp.float32)
+                 for k in SUMMED if f"{k}_sum" in met},
+                micro_steps=met["micro_steps"] + jnp.int32(1))
             return (params, opt_state, comp, step + jnp.int32(1), met), None
 
         (p, o, comp, step, met), _ = jax.lax.scan(
@@ -244,11 +249,9 @@ def abstract_state(cfg: ModelConfig, mesh, opt: Optimizer,
         "comp": acomp,
         "step": jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
         "key": jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=rep),
-        "metrics": {
-            "loss_sum": jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
-            "grad_norm_sum": jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
-            "micro_steps": jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
-        },
+        "metrics": jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep),
+            jax.eval_shape(lambda: zero_metrics(cfg))),
     }
 
 

@@ -1,4 +1,4 @@
-"""GQA attention: plain, chunked (online-softmax), and decode-with-cache.
+"""GQA attention: plain, chunked (online-softmax), and the fused-kernel dispatch.
 
 Shapes use the grouped layout throughout: q (B, S, KV, G, hd) where
 H = KV·G query heads share KV heads; k/v (B, S, KV, hd). This avoids ever
@@ -19,6 +19,7 @@ the XLA paths, plain or chunked by ``kv_block``.
 """
 from __future__ import annotations
 
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,13 @@ import jax.numpy as jnp
 from repro.kernels import attention as fused
 
 NEG_INF = -1e30
+
+
+def _scale(q: jax.Array, scale: Optional[float]):
+    """The scores' scale: ``scale``, or 1/sqrt(hd) when it is None."""
+    if scale is None:
+        return 1.0 / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
+    return scale
 
 
 def _mask(qpos: jax.Array, kpos: jax.Array, causal: bool, window: int) -> jax.Array:
@@ -45,10 +53,10 @@ def plain_attention(
     causal: bool = True,
     window: int = 0,
     q_offset: int = 0,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Reference full-materialisation attention (small S / tests)."""
-    hd = q.shape[-1]
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
+    scale = _scale(q, scale)
     logits = jnp.einsum("bqkgd,bskd->bkgqs", q.astype(jnp.float32), k.astype(jnp.float32)) * scale
     qpos = q_offset + jnp.arange(q.shape[1])
     kpos = jnp.arange(k.shape[1])
@@ -67,6 +75,7 @@ def chunked_attention(
     window: int = 0,
     q_offset: int = 0,
     kv_block: int = 1024,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Online-softmax attention over KV blocks; O(Sq·kv_block) live memory."""
     b, sq, kv, g, hd = q.shape
@@ -81,7 +90,7 @@ def chunked_attention(
     nblk = sk_p // kv_block
     kb = k.reshape(b, nblk, kv_block, kv, hd).transpose(1, 0, 2, 3, 4)
     vb = v.reshape(b, nblk, kv_block, kv, hd).transpose(1, 0, 2, 3, 4)
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
+    scale = _scale(q, scale)
     qf = q.astype(jnp.float32)
     qpos = q_offset + jnp.arange(sq)
 
@@ -121,42 +130,16 @@ def fused_kernel_applies(sq: int, sk: int, causal: bool, q_offset: int,
 
 def attention(
     q, k, v, causal: bool = True, window: int = 0, q_offset: int = 0,
-    kv_block: int = 1024, shards: int = 1,
+    kv_block: int = 1024, shards: int = 1, scale: Optional[float] = None,
 ):
     """Dispatch: the fused kernel where it applies (see
     ``fused_kernel_applies``; ``shards`` is the number of devices the
     compiler partitions the trace over, ``ShardCtx.auto_shards``), else
-    plain for short sequences (or ``kv_block`` 0) and chunked otherwise."""
+    plain for short sequences (or ``kv_block`` 0) and chunked otherwise.
+    ``scale`` multiplies the scores (default 1/sqrt(hd))."""
     if fused_kernel_applies(q.shape[1], k.shape[1], causal, q_offset, shards,
                             jax.default_backend()):
-        return fused.causal_attention(q, k, v, window)
+        return fused.causal_attention(q, k, v, window, scale=scale)
     if kv_block == 0 or k.shape[1] <= kv_block:
-        return plain_attention(q, k, v, causal, window, q_offset)
-    return chunked_attention(q, k, v, causal, window, q_offset, kv_block)
-
-
-def decode_attention(
-    q: jax.Array,  # (B, 1, KV, G, hd)
-    k_cache: jax.Array,  # (B, S_cache, KV, hd) — includes the new token
-    v_cache: jax.Array,
-    pos: jax.Array,  # scalar: absolute position of the new token
-    window: int = 0,
-    pos_offset: int = 0,
-) -> jax.Array:
-    """Single-token attention against a (possibly ring-buffer) cache.
-
-    ``pos_offset`` maps cache slot s to absolute position (ring buffers:
-    slot s holds absolute position pos_offset + s ... used as 0 for linear
-    caches where slot == absolute position).
-    """
-    hd = q.shape[-1]
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
-    logits = jnp.einsum("bqkgd,bskd->bkgqs", q.astype(jnp.float32), k_cache.astype(jnp.float32)) * scale
-    kpos = pos_offset + jnp.arange(k_cache.shape[1])
-    ok = kpos <= pos
-    if window and window > 0:
-        ok &= kpos > pos - window
-    logits = jnp.where(ok[None, None, None, None, :], logits, NEG_INF)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", p, v_cache.astype(jnp.float32))
-    return out.astype(q.dtype)
+        return plain_attention(q, k, v, causal, window, q_offset, scale)
+    return chunked_attention(q, k, v, causal, window, q_offset, kv_block, scale)

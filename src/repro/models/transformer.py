@@ -14,9 +14,15 @@ with any remainder layers unrolled. Whisper adds an encoder stack and
 cross-attention; VLM/audio frontends are stubs that consume precomputed
 patch/frame embeddings (see DESIGN.md — the one allowed stub).
 
+Granite's residual and logit path (``ModelConfig.embedding_multiplier``,
+``attention_multiplier``, ``residual_multiplier``, ``logits_scaling``,
+``tie_embeddings``) applies on every path; at their defaults the model is
+as before.  A tied model keeps one (D, V) matrix, the ``lm_head`` leaf,
+and embeds a token as its column.
+
 Entry points:
   init_params(cfg, key)                  -> params pytree
-  loss_fn(params, batch, cfg, ...)       -> scalar loss (train)
+  loss_fn(params, batch, cfg, ...)       -> (scalar loss, stats) (train)
   prefill(params, batch, cfg, ...)       -> (logits_last, cache)
   decode_step(params, token, cache, pos, cfg, ...) -> (logits, cache)
 """
@@ -47,6 +53,37 @@ Params = Dict[str, Any]
 
 def _dt(cfg: ModelConfig):
     return jnp.dtype(cfg.dtype)
+
+
+def _times(x: jax.Array, multiplier: float) -> jax.Array:
+    """``x`` times a configuration's multiplier (no op at 1)."""
+    return x if multiplier == 1.0 else x * multiplier
+
+
+def _embed(params: Params, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Token embeddings times ``embedding_multiplier``: rows of ``embed``,
+    or for a tied model columns of ``lm_head``."""
+    table = params["lm_head"].T if cfg.tie_embeddings else params["embed"]
+    return _times(table.astype(_dt(cfg))[tokens], cfg.embedding_multiplier)
+
+
+def _logits(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    logits = x @ params["lm_head"]
+    return logits if cfg.logits_scaling == 1.0 else logits / cfg.logits_scaling
+
+
+def _no_aux() -> Dict[str, jax.Array]:
+    """A layer without experts: a load-balance term of 0."""
+    return {"balance": jnp.float32(0.0)}
+
+
+def _merge_aux(total: Dict[str, jax.Array], a: Dict[str, jax.Array]):
+    """Layer statistics over the layers: the load-balance terms add up,
+    the expert load is the largest layer's."""
+    out = dict(total, balance=total["balance"] + a["balance"])
+    if "expert_load" in a:
+        out["expert_load"] = jnp.maximum(total["expert_load"], a["expert_load"])
+    return out
 
 
 def _init_attn_layer(key, cfg: ModelConfig, dtype) -> Params:
@@ -147,10 +184,11 @@ def init_params(cfg: ModelConfig, key) -> Params:
     std = 0.02
     k_embed, k_head, k_layers, k_enc, k_cross, k_tail, k_fe = jax.random.split(key, 7)
     params: Params = {
-        "embed": (std * jax.random.normal(k_embed, (cfg.vocab, cfg.d_model))).astype(dtype),
         "final_norm": jnp.zeros((cfg.d_model,), dtype),
         "lm_head": (std * jax.random.normal(k_head, (cfg.d_model, cfg.vocab))).astype(dtype),
     }
+    if not cfg.tie_embeddings:
+        params["embed"] = (std * jax.random.normal(k_embed, (cfg.vocab, cfg.d_model))).astype(dtype)
     groups, tail = layer_groups(cfg)
     (pattern, n_super) = groups[0]
 
@@ -237,9 +275,9 @@ def _attn_layer_fwd(
         k = ctx.constrain(k, ("b", None, "m", None))
         v = ctx.constrain(v, ("b", None, "m", None))
         o = attn_lib.attention(q, k, v, causal=causal, window=window, kv_block=kv_block,
-                               shards=ctx.auto_shards())
+                               shards=ctx.auto_shards(), scale=cfg.attention_multiplier or None)
         o = o.reshape(b, s, h * hd) @ p["wo"]
-        x = x + o
+        x = x + _times(o, cfg.residual_multiplier)
         if enc_out is not None and cross_p is not None:
             yc = L.rms_norm(x, cross_p["ln1"], cfg.norm_eps)
             qc = (yc @ cross_p["wq"]).reshape(b, s, kv, g, hd)
@@ -249,15 +287,17 @@ def _attn_layer_fwd(
             x = x + oc.reshape(b, s, h * hd) @ cross_p["wo"]
     with jax.named_scope(spans.MLP):
         y = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-        aux = jnp.float32(0.0)
+        aux = _no_aux()
         if cfg.moe is not None:
-            f, aux = moe_lib.moe_ffn(y, p["router"], p["we_g"], p["we_u"], p["we_d"], cfg.moe.top_k)
+            f, balance, load = moe_lib.moe_ffn(y, p["router"], p["we_g"], p["we_u"], p["we_d"],
+                                               cfg.moe.top_k, shards=ctx.auto_shards())
+            aux = {"balance": balance, "expert_load": load}
             f = ctx.constrain(f, ("b", None, None))
-            x = x + f
+            x = x + _times(f, cfg.residual_multiplier)
         elif cfg.d_ff:
             hdn = jax.nn.silu(y @ p["wg"]) * (y @ p["wu"])
             hdn = ctx.constrain(hdn, ("b", None, "m"))
-            x = x + hdn @ p["wd"]
+            x = x + _times(hdn @ p["wd"], cfg.residual_multiplier)
     if return_kv:
         return x, aux, (k, v)
     return x, aux
@@ -283,7 +323,7 @@ def _ssm_layer_fwd(p: Params, x: jax.Array, cfg: ModelConfig, ctx: ShardCtx):
     y_ssd = y_ssd + p["D_skip"][None, None, :, None].astype(y_ssd.dtype) * xh
     y_out = y_ssd.reshape(b, s, di) * jax.nn.silu(z)
     y_out = L.rms_norm(y_out, p["out_norm"], cfg.norm_eps)
-    return x + y_out @ p["w_out"], jnp.float32(0.0)
+    return x + y_out @ p["w_out"], _no_aux()
 
 
 def _rec_layer_fwd(p: Params, x: jax.Array, cfg: ModelConfig, ctx: ShardCtx):
@@ -297,7 +337,7 @@ def _rec_layer_fwd(p: Params, x: jax.Array, cfg: ModelConfig, ctx: ShardCtx):
     y = L.rms_norm(x, p["ln2"], cfg.norm_eps)
     hdn = jax.nn.gelu(y @ p["wg"]) * (y @ p["wu"])
     hdn = ctx.constrain(hdn, ("b", None, "m"))
-    return x + hdn @ p["wd"], jnp.float32(0.0)
+    return x + hdn @ p["wd"], _no_aux()
 
 
 def _attn_window(cfg: ModelConfig) -> int:
@@ -347,11 +387,14 @@ def forward(
     remat: bool = True,
     kv_block: int = 1024,
     block_provider=None,  # FSDP: per-block weight gather (see launch/steps.py)
-) -> Tuple[jax.Array, jax.Array]:
-    """Full-sequence forward -> (logits (B, S_text, V), aux_loss)."""
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Full-sequence forward -> (logits (B, S_text, V), aux): ``aux`` holds
+    the load-balance term summed over layers (``balance``) and, for a
+    model with experts, the largest layer's expert load
+    (``expert_load``: its largest expert's routed rows over the mean)."""
     b, s = tokens.shape
     with jax.named_scope(spans.EMBED):
-        x = params["embed"].astype(_dt(cfg))[tokens]
+        x = _embed(params, tokens, cfg)
     enc_out = None
     n_prefix = 0
     if cfg.frontend == "audio" and cfg.n_enc_layers:
@@ -362,7 +405,9 @@ def forward(
         x = jnp.concatenate([frontend.astype(x.dtype), x], axis=1)
         n_prefix = frontend.shape[1]
     x = ctx.constrain(x, ("b", None, None))
-    aux_total = jnp.float32(0.0)
+    aux_total = _no_aux()
+    if cfg.moe is not None:
+        aux_total["expert_load"] = jnp.float32(0.0)
     groups, tail = layer_groups(cfg)
     (pattern, n_super) = groups[0]
     positions = jnp.arange(x.shape[1])[None, :]
@@ -385,7 +430,7 @@ def forward(
             x, a = _layer_fwd(kind, block_p[f"p{i}_{kind}"], x, cfg, ctx, **kw)
             if ctx.seq_parallel:
                 x = ctx.constrain(x, ("b", "m", None))  # residual S-sharded
-            aux = aux + a
+            aux = _merge_aux(aux, a)
         return (x, aux), None
 
     if remat:
@@ -395,12 +440,12 @@ def forward(
     for i, kind in enumerate(tail):
         kw = dict(positions=positions, kv_block=kv_block) if kind == "attn" else {}
         x, a = _layer_fwd(kind, params["tail"][i], x, cfg, ctx, **kw)
-        aux_total = aux_total + a
+        aux_total = _merge_aux(aux_total, a)
     with jax.named_scope(spans.HEAD_LOSS):
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         if n_prefix:
             x = x[:, n_prefix:]
-        logits = x @ params["lm_head"]
+        logits = _logits(params, x, cfg)
         logits = ctx.constrain(logits, ("b", None, "m"))
     return logits, aux_total
 
@@ -414,14 +459,18 @@ def loss_fn(
     kv_block: int = 1024,
     aux_weight: float = 0.01,
     block_provider=None,
-) -> jax.Array:
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """-> (cross entropy + ``aux_weight`` x the load-balance term, stats):
+    ``stats`` holds ``forward``'s statistics other than that term (the
+    expert load of a model with experts; none otherwise)."""
     logits, aux = forward(
         params, batch["tokens"], cfg, ctx, frontend=batch.get("frontend"),
         remat=remat, kv_block=kv_block, block_provider=block_provider,
     )
+    stats = {k: v for k, v in aux.items() if k != "balance"}
     with jax.named_scope(spans.HEAD_LOSS):
         loss = L.cross_entropy(logits, batch["labels"], batch.get("mask"))
-        return loss + aux_weight * aux
+        return loss + aux_weight * aux["balance"], stats
 
 
 # ---------------------------------------------------------------------------
@@ -510,8 +559,8 @@ def _attn_decode(p, x, lc, cfg: ModelConfig, ctx: ShardCtx, pos, window: int,
     kpos = jax.lax.dynamic_update_slice_in_dim(lc["kpos"], jnp.full((1,), pos, jnp.int32), slot, axis=0)
     k_cache = ctx.constrain(k_cache, ("b", None, "m", None))
     v_cache = ctx.constrain(v_cache, ("b", None, "m", None))
-    o = _cache_attention(q, k_cache, v_cache, kpos, pos, window)
-    x = x + o.reshape(b, 1, h * hd) @ p["wo"]
+    o = _cache_attention(q, k_cache, v_cache, kpos, pos, window, cfg.attention_multiplier or None)
+    x = x + _times(o.reshape(b, 1, h * hd) @ p["wo"], cfg.residual_multiplier)
     if cross_kv is not None and cross_p is not None:
         yc = L.rms_norm(x, cross_p["ln1"], cfg.norm_eps)
         qc = (yc @ cross_p["wq"]).reshape(b, 1, kv, g, hd)
@@ -521,16 +570,18 @@ def _attn_decode(p, x, lc, cfg: ModelConfig, ctx: ShardCtx, pos, window: int,
         x = x + oc.reshape(b, 1, h * hd) @ cross_p["wo"]
     y = L.rms_norm(x, p["ln2"], cfg.norm_eps)
     if cfg.moe is not None:
-        f, _ = moe_lib.moe_ffn(y, p["router"], p["we_g"], p["we_u"], p["we_d"], cfg.moe.top_k)
-        x = x + f
+        f = moe_lib.moe_ffn(y, p["router"], p["we_g"], p["we_u"], p["we_d"], cfg.moe.top_k,
+                            shards=ctx.auto_shards())[0]
+        x = x + _times(f, cfg.residual_multiplier)
     elif cfg.d_ff:
-        x = x + (jax.nn.silu(y @ p["wg"]) * (y @ p["wu"])) @ p["wd"]
+        x = x + _times((jax.nn.silu(y @ p["wg"]) * (y @ p["wu"])) @ p["wd"],
+                       cfg.residual_multiplier)
     return x, {"k": k_cache, "v": v_cache, "kpos": kpos}
 
 
-def _cache_attention(q, k_cache, v_cache, kpos, pos, window: int):
-    hd = q.shape[-1]
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
+def _cache_attention(q, k_cache, v_cache, kpos, pos, window: int, scale=None):
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
     logits = jnp.einsum("bqkgd,bskd->bkgqs", q.astype(jnp.float32), k_cache.astype(jnp.float32)) * scale
     ok = (kpos >= 0) & (kpos <= pos)
     if window:
@@ -585,7 +636,7 @@ def decode_step(
     ctx: ShardCtx = NULL_CTX,
 ) -> Tuple[jax.Array, Params]:
     """One decode step: returns (logits (B, 1, V), updated cache)."""
-    x = params["embed"].astype(_dt(cfg))[token]
+    x = _embed(params, token, cfg)
     groups, tail = layer_groups(cfg)
     (pattern, n_super) = groups[0]
     window_attn = cfg.local_window if cfg.hybrid_pattern else (
@@ -629,7 +680,7 @@ def decode_step(
             new_tail.append(nc)
         new_cache["tail"] = new_tail
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = x @ params["lm_head"]
+    logits = _logits(params, x, cfg)
     logits = ctx.constrain(logits, ("b", None, "m"))
     return logits, new_cache
 
@@ -653,7 +704,7 @@ def prefill(
     cache_len = cache_len or s
     assert cache_len >= s, (cache_len, s)
     cache = init_cache(cfg, b, cache_len)
-    x = params["embed"].astype(_dt(cfg))[tokens]
+    x = _embed(params, tokens, cfg)
     enc_out = None
     if cfg.frontend == "audio" and cfg.n_enc_layers:
         enc_out = _encoder_fwd(params, frontend, cfg, ctx, remat=False, kv_block=kv_block)
@@ -718,7 +769,7 @@ def prefill(
                 new_tail.append(st)
         cache["tail"] = new_tail
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = x[:, -1:] @ params["lm_head"]
+    logits = _logits(params, x[:, -1:], cfg)
     return logits, cache
 
 

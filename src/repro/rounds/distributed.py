@@ -117,7 +117,9 @@ def scan_local_sgd(value_and_grad_fn, w, tau: int, eta):
     by the distributed integrations (launch/steps train step and
     :func:`make_local_update_round`), so the accumulation semantics the
     DESIGN.md τ-interpolation claims rest on live in a single place.
-    ``value_and_grad_fn(p) -> (loss, grad)`` closes over the local batch.
+    ``value_and_grad_fn(p) -> (loss, grad)`` closes over the local batch;
+    ``loss`` may be a pytree (a loss with statistics), of which the
+    round's first step is returned.
     """
 
     def local_step(carry, _):
@@ -128,7 +130,7 @@ def scan_local_sgd(value_and_grad_fn, w, tau: int, eta):
 
     zeros = jax.tree.map(jnp.zeros_like, w)
     (_, delta), losses = jax.lax.scan(local_step, (w, zeros), None, length=tau)
-    return delta, losses[0]
+    return delta, jax.tree.map(lambda l: l[0], losses)
 
 
 def make_local_update_round(

@@ -42,8 +42,8 @@ def test_forward_and_train_step(arch):
     # one real optimizer step reduces nothing but must stay finite
     opt = get_optimizer("adamw", 1e-3)
     state = opt.init(params)
-    loss, grads = jax.jit(jax.value_and_grad(
-        lambda p: T.loss_fn(p, batch, cfg, kv_block=16)))(params)
+    (loss, _), grads = jax.jit(jax.value_and_grad(
+        lambda p: T.loss_fn(p, batch, cfg, kv_block=16), has_aux=True))(params)
     assert bool(jnp.isfinite(loss))
     new_params, _ = opt.update(grads, state, params, jnp.int32(0))
     for leaf in jax.tree.leaves(new_params):

@@ -176,3 +176,30 @@ def test_fused_attention_cuts_the_window_steps_temporaries(topo, monkeypatch):
     assert not xla_kernel and has_kernel
     assert mode.TRACES[("splash_attention", False)] > before
     assert fused_bytes < xla / 2, (fused_bytes, xla)
+
+
+# the train cell's grouped products at granite-3.0-1b-a400m's widths:
+# 8192 tokens x 8 slots over 32 experts of 1024 x 512
+GMM = dict(rows=65536, d=1024, f=512, e=32)
+
+
+def test_grouped_matmul_compiles_forward_and_backward(one_chip):
+    """Mosaic takes the megablox kernels at the cell's shapes and tiling:
+    the forward product, the rows' gradient and the matrices' gradient
+    are three kernels in the compiled program, named by their scopes."""
+    from repro.kernels import grouped_matmul as G
+
+    rows, d, f, e = (GMM[k] for k in ("rows", "d", "f", "e"))
+    lhs = jax.ShapeDtypeStruct((rows, d), jnp.bfloat16, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((e, d, f), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((e,), jnp.int32, sharding=one_chip)
+
+    def loss(lhs, rhs, sizes):
+        return jnp.sum(G.gmm(lhs, rhs, sizes, interpret=False).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(lhs, rhs, sizes).compile(
+    ).as_text()
+    kernels = [line.split(" = ")[0] for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(kernels) == 3, kernels
+    assert sum("moe_tgmm" in k for k in kernels) == 1
+    assert sum("moe_gmm" in k for k in kernels) == 2

@@ -23,9 +23,13 @@ One chip:
    published widths and settings (dropless top-8 of 32 experts, tied
    head, the four multipliers) with ``n_layers`` cut to 2, seq 4096,
    global batch 2, bucketed median, 4 steps in windows of 2: finite loss,
-   |g| and expert load, no compilation after the first window; then the
-   grouped-matmul kernel against ``jax.lax.ragged_dot`` at the train
-   cell's shapes, forward and backward;
+   |g| and expert load, no compilation after the first window; the
+   token permutation's census of that window step as lowered for the
+   chip (``hlo_analysis.permutation_census``): one sort of the T·k
+   (token, slot) keys per MoE layer body, no (T, k, D) broadcast and no
+   scatter of T·k rows; then the grouped-matmul kernel against
+   ``jax.lax.ragged_dot`` at the train cell's shapes, forward and
+   backward;
 4. kernels: ``kernels.ops.robust_aggregate(backend="pallas")`` (median,
    trimmed mean) against ``kernels/ref.py``, and one ``repro.fed.run``
    round aggregate (approx_median, default sketch settings) on the
@@ -223,6 +227,11 @@ def phase_moe(counter, seed):
                             seq=MOE_SEQ, batch=MOE_BATCH)
     check_train(result, win, STEPS, DEVICE_STEPS)
     result.state.clear()
+    census = moe_census()
+    print(f"    permutation census of the lowered window step: {census}", flush=True)
+    check(census == {"sorts": 1, "broadcasts": 0, "scatters": 0},
+          "one sort of the (token, slot) keys, no (T, k, D) broadcast, no scatter of "
+          "T·k rows")
 
     # the train cell's grouped product: 8192 tokens x 8 slots over 32
     # experts of 1024 x 512, rows routed by a random top-8
@@ -251,6 +260,31 @@ def phase_moe(counter, seed):
         # both accumulate bf16 products in f32 and round the result to
         # bf16 once: they differ by the summation order, far under 1e-2
         check(rel <= 1e-2, f"grouped matmul {what} agrees with ragged_dot")
+
+
+def moe_census():
+    """``hlo_analysis.permutation_census`` of the sparse-experts phase's
+    window step (remat on, as ``run_train`` builds it), lowered for this
+    chip, at T = seq x batch tokens."""
+    from repro.configs import get_config
+    from repro.configs.base import ParallelConfig, ShapeConfig
+    from repro.core.attacks import AttackConfig
+    from repro.launch import trainer
+    from repro.launch.hlo_analysis import permutation_census
+    from repro.launch.mesh import make_debug_mesh
+    from repro.optim.optimizers import get_optimizer
+
+    cfg = dataclasses.replace(get_config(MOE_ARCH), n_layers=MOE_LAYERS)
+    mesh = make_debug_mesh(1, 1)
+    pcfg = ParallelConfig(agg_method="median", agg_strategy="bucketed", remat=True,
+                          attn_chunk=0)
+    opt = get_optimizer("adamw", LR)
+    window = trainer.make_window_step(cfg, pcfg, mesh, opt, AttackConfig("none", 0.0),
+                                      device_steps=DEVICE_STEPS)
+    shape = ShapeConfig("smoke", MOE_SEQ, MOE_BATCH, "train")
+    text = window.lower(trainer.abstract_state(cfg, mesh, opt, pcfg),
+                        trainer.abstract_window_batches(cfg, shape, mesh, DEVICE_STEPS)).as_text()
+    return permutation_census(text, MOE_SEQ * MOE_BATCH, cfg.moe.top_k, cfg.d_model)
 
 
 def check_compiled_kernels(names) -> None:

@@ -21,6 +21,7 @@ exactly what the roofline terms need.
 from __future__ import annotations
 
 import dataclasses
+import math
 import re
 from typing import Dict, List, Optional, Tuple
 
@@ -315,3 +316,73 @@ def analyze(text: str) -> Dict[str, float]:
     for k in _COLLECTIVES:
         out[f"coll_{k}"] = coll.get(k, 0.0)
     return out
+
+
+# --- the expert layer's token permutation in a lowered (StableHLO) program
+
+_FUNC_RE = re.compile(r"^\s*func\.func (?:public |private )?@([\w.$\-]+)\(", re.M)
+_CALL_RE = re.compile(r"\bcall @([\w.$\-]+)\(")
+_TENSOR_RE = re.compile(r"tensor<((?:\d+x)*)([a-z]\w*)>")
+_SIGNATURE_RE = re.compile(r"\}\) : \(([^)]*)\) -> ")
+_BROADCAST_RE = re.compile(r"stablehlo\.broadcast_in_dim .*-> tensor<((?:\d+x)*)[a-z]\w*>")
+
+
+def _dims(shape: str) -> List[int]:
+    return [int(s) for s in shape.split("x") if s]
+
+
+def _region_types(body: str, op: str) -> List[List[List[int]]]:
+    """For each generic-form ``op`` (one with a region, e.g. sort or
+    scatter), the shapes of its operands, read from the signature after
+    its region."""
+    out = []
+    for m in re.finditer(rf'"stablehlo\.{op}"\(', body):
+        sig = _SIGNATURE_RE.search(body, m.end())
+        if sig:
+            out.append([_dims(s) for s, _ in _TENSOR_RE.findall(sig.group(1))])
+    return out
+
+
+def permutation_census(text: str, t: int, k: int, d: int) -> Dict[str, int]:
+    """How many times one call of a lowered program's entry runs each pass
+    of an expert layer's token permutation that a dropless layer with T
+    tokens, top-k and width D can do without:
+
+    - ``sorts``: sorts whose keys are T·k long;
+    - ``broadcasts``: broadcasts to a (…, k, D) buffer of T·k·D elements;
+    - ``scatters``: scatters whose updates are T·k rows of D.
+
+    ``text`` is ``jax.jit(f).lower(...).as_text()``; a loop body counts
+    once, a private function once per call.  The layer as built in
+    ``models/moe.py`` reads ``sorts`` 1 (the sort by expert, in the
+    forward pass: the remat'd block saves the routing) and 0 of the rest,
+    per MoE layer body."""
+    starts = [(m.start(), m.group(1)) for m in _FUNC_RE.finditer(text)]
+    bodies = {name: text[s:e] for (s, name), (e, _) in
+              zip(starts, starts[1:] + [(len(text), "")])}
+    n = t * k
+
+    def direct(body: str) -> Dict[str, int]:
+        sorts = sum(1 for types in _region_types(body, "sort")
+                    if types and types[0] == [n])
+        scatters = sum(1 for types in _region_types(body, "scatter")
+                       if len(types) == 3 and types[2][-1:] == [d]
+                       and math.prod(types[2]) == n * d)
+        broadcasts = sum(1 for s in _BROADCAST_RE.findall(body)
+                         if _dims(s)[-2:] == [k, d] and math.prod(_dims(s)) == n * d)
+        return {"sorts": sorts, "broadcasts": broadcasts, "scatters": scatters}
+
+    memo: Dict[str, Dict[str, int]] = {}
+
+    def total(name: str) -> Dict[str, int]:
+        if name not in memo:
+            body = bodies.get(name, "")
+            out = direct(body)
+            for callee in _CALL_RE.findall(body):
+                for key, v in total(callee).items():
+                    out[key] += v
+            memo[name] = out
+        return memo[name]
+
+    entry = "main" if "main" in bodies else starts[0][1]
+    return total(entry)

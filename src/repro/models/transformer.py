@@ -434,7 +434,10 @@ def forward(
         return (x, aux), None
 
     if remat:
-        block_body = jax.checkpoint(block_body, prevent_cse=False)
+        # the remat'd block keeps the expert layer's routing for its backward
+        # pass; a dense block names none of it, so saves nothing
+        policy = jax.checkpoint_policies.save_only_these_names(*moe_lib.ROUTING)
+        block_body = jax.checkpoint(block_body, prevent_cse=False, policy=policy)
     xs = (params["blocks"], params["cross_blocks"] if has_cross else None)
     (x, aux_total), _ = jax.lax.scan(block_body, (x, aux_total), xs)
     for i, kind in enumerate(tail):

@@ -4,7 +4,12 @@ logit path in ``models/transformer.py``.
 
 The layer must equal a dense formula, every expert on every token times
 a gate that is zero off the top-k, forward and backward, also when every
-token goes to one expert (a capacity would drop rows there).  The
+token goes to one expert (a capacity would drop rows there).  Its token
+permutation (one sort, routing saved for the remat'd backward, dispatch
+and combine with backward passes that only gather) must give the forward
+of the same gathers under plain autodiff bit for bit, and their
+gradients to rounding; the lowered step must hold one sort of the T·k
+(token, slot) keys and no (T, k, D) broadcast or (T·k, D) scatter.  The
 megablox kernel, run interpreted, must equal ``jax.lax.ragged_dot``.
 """
 import dataclasses
@@ -16,8 +21,10 @@ import pytest
 
 from repro.configs import get_config, get_smoke_config
 from repro.kernels import grouped_matmul as G
+from repro.launch.hlo_analysis import permutation_census
 from repro.models import moe
 from repro.models import transformer as T
+from repro.models.sharding import NULL_CTX
 
 T_, D, F, E, K = 48, 32, 16, 8, 3
 
@@ -73,6 +80,188 @@ def test_layer_equals_the_dense_formula(one_expert):
     for name in w:
         np.testing.assert_allclose(got[name], want[name], rtol=2e-4, atol=2e-5,
                                    err_msg=name)
+
+
+def _plain_gathers(x, router, wg, wu, wd, k):
+    """The layer's math with its permutation as plain gathers under jax's
+    autodiff (whose backward passes scatter): the tokens broadcast to
+    (T, k, D) and gathered by expert, the inverse permutation a second
+    argsort.  The forward pass of ``moe_ffn`` must equal it bit for bit."""
+    b, s, d = x.shape
+    e = router.shape[1]
+    t = b * s
+    xf = x.reshape(t, d)
+    logits = jnp.dot(xf.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top_logits, top_idx = jax.lax.top_k(logits, k)
+    gates = jax.nn.softmax(top_logits, axis=-1)
+    experts = top_idx.reshape(t * k)
+    order = jnp.argsort(experts, stable=True)
+    inverse = jnp.argsort(order)
+    sizes = jnp.sum(jax.nn.one_hot(experts, e, dtype=jnp.int32), axis=0)
+    xs = jnp.broadcast_to(xf[:, None], (t, k, d)).reshape(t * k, d)[order]
+    h = jax.nn.silu(G.ragged(xs, wg, sizes)) * G.ragged(xs, wu, sizes)
+    ys = G.ragged(h.astype(x.dtype), wd, sizes)
+    slots = ys[inverse].reshape(t, k, d)
+    y = jnp.einsum("tkd,tk->td", slots.astype(jnp.float32), gates).astype(x.dtype)
+    frac = sizes.astype(jnp.float32) / t
+    mean_p = jnp.mean(jax.nn.softmax(logits, axis=-1), axis=0)
+    aux = e * jnp.sum(frac * mean_p) / k
+    load = jnp.max(sizes).astype(jnp.float32) * e / (t * k)
+    return y.reshape(b, s, d), aux, load
+
+
+# (top-k, experts, batch, sequence, dtype, routing): "starve" keeps expert
+# 5 off every token's top-k, "favour" puts expert 3 first on every token;
+# 37 tokens x top-3 = 111 rows fit no tile; decode is T = B
+PERMUTATION_CASES = {
+    "k1": (1, 8, 2, 24, jnp.float32, None),
+    "k2": (2, 8, 2, 24, jnp.float32, None),
+    "k8": (8, 16, 2, 24, jnp.float32, None),
+    "expert_with_zero_rows": (2, 8, 2, 24, jnp.float32, "starve"),
+    "all_rows_on_one_expert": (1, 8, 2, 24, jnp.float32, "favour"),
+    "rows_fit_no_tile": (3, 8, 1, 37, jnp.float32, None),
+    "bf16": (2, 8, 2, 24, jnp.bfloat16, None),
+    "decode": (2, 8, 4, 1, jnp.float32, None),
+}
+
+
+def _case(name):
+    k, e, b, s, dtype, routing = PERMUTATION_CASES[name]
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    x = jax.random.normal(ks[0], (b, s, D))
+    router = 0.3 * jax.random.normal(ks[1], (D, e))
+    if routing == "starve":
+        x, router = jnp.abs(x), router.at[:, 5].set(-5.0)
+    elif routing == "favour":
+        x, router = jnp.abs(x), router.at[:, 3].set(5.0)
+    w = dict(x=x, router=router,
+             wg=0.2 * jax.random.normal(ks[2], (e, D, F)),
+             wu=0.2 * jax.random.normal(ks[3], (e, D, F)),
+             wd=0.2 * jax.random.normal(ks[4], (e, F, D)))
+    w = {n: v.astype(dtype) for n, v in w.items()}
+    ct = jax.random.normal(ks[5], (b, s, D))
+    return w, k, ct
+
+
+def _grads(layer, w, k, ct, balance):
+    def loss(w):
+        y, aux, _ = layer(w["x"], w["router"], w["wg"], w["wu"], w["wd"], k)
+        return jnp.sum(y.astype(jnp.float32) * ct) + balance * aux
+    return jax.grad(loss)(w)
+
+
+@pytest.mark.parametrize("name", list(PERMUTATION_CASES))
+def test_permutation_matches_plain_gathers_and_the_dense_formula(name):
+    w, k, ct = _case(name)
+    e = w["router"].shape[1]
+    args = (w["x"], w["router"], w["wg"], w["wu"], w["wd"], k)
+    got, want = moe.moe_ffn(*args), _plain_gathers(*args)
+    for g, w_, what in zip(got, want, ("output", "balance term", "load")):
+        np.testing.assert_array_equal(g, w_, err_msg=what)
+    t = w["x"].shape[0] * w["x"].shape[1]
+    chosen = np.asarray(jax.lax.top_k(jnp.dot(
+        w["x"].reshape(t, D).astype(jnp.float32), w["router"].astype(jnp.float32),
+        precision="highest"), k)[1])
+    if name == "expert_with_zero_rows":
+        assert 5 not in chosen
+    if name == "all_rows_on_one_expert":
+        assert (chosen == 3).all() and float(got[2]) == e
+
+    # gradients, the balance term's included, against the plain gathers:
+    # they differ only in the order of f32 sums (and, in bf16, where the
+    # plain backward adds a token's k slots in bf16 and moe_ffn in f32)
+    bf16 = w["x"].dtype == jnp.bfloat16
+    got_g = _grads(moe.moe_ffn, w, k, ct, 0.1)
+    want_g = _grads(_plain_gathers, w, k, ct, 0.1)
+    for n in w:
+        a, b_ = np.asarray(got_g[n], np.float32), np.asarray(want_g[n], np.float32)
+        scale = float(np.abs(b_).max())
+        tol = (2.0 ** -6 if bf16 else 1e-5)
+        np.testing.assert_allclose(a, b_, rtol=tol, atol=tol * scale, err_msg=n)
+
+    # and the dense formula, every expert on every token (in f32 from the
+    # same weights: bf16 rounds the products' inputs and outputs)
+    up = {n: v.astype(jnp.float32) for n, v in w.items()}
+    dense = _dense(up["x"], up["router"], up["wg"], up["wu"], up["wd"], k)
+    rel = 2e-2 if bf16 else 1e-5
+    np.testing.assert_allclose(np.asarray(got[0], np.float32), dense, rtol=rel,
+                               atol=rel * float(np.abs(dense).max()))
+    want_d = _grads(lambda *a: (_dense(*a), 0.0, 0.0), up, k, ct, 0.0)
+    got_d = _grads(moe.moe_ffn, w, k, ct, 0.0)
+    for n in w:
+        a, b_ = np.asarray(got_d[n], np.float32), np.asarray(want_d[n])
+        if bf16:
+            assert np.linalg.norm(a - b_) <= 2e-2 * np.linalg.norm(b_), n
+        else:
+            np.testing.assert_allclose(a, b_, rtol=2e-4, atol=2e-5, err_msg=n)
+
+
+def _granite_block():
+    cfg = dataclasses.replace(get_smoke_config("granite-moe-1b-a400m"), n_layers=1)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 24), 0, cfg.vocab)
+    return cfg, params, {"tokens": toks, "labels": toks}
+
+
+def test_remat_keeps_the_routing_and_sorts_once():
+    """The gradient of a remat'd granite block (one MoE layer, 48 tokens,
+    top-2 of 4 experts, width 128), lowered on the CPU: one sort of the 96
+    (token, slot) keys, no (T, k, D) broadcast, no scatter of 96 rows; the
+    remat'd gradient equals the one with nothing recomputed."""
+    cfg, params, batch = _granite_block()
+    t, k, d = 48, cfg.moe.top_k, cfg.d_model
+
+    def grad(remat):
+        return jax.jit(jax.grad(lambda p: T.loss_fn(p, batch, cfg, remat=remat,
+                                                    kv_block=0)[0]))
+
+    text = grad(True).lower(params).as_text()
+    assert permutation_census(text, t, k, d) == {"sorts": 1, "broadcasts": 0, "scatters": 0}
+    for a, b_ in zip(jax.tree.leaves(grad(True)(params)), jax.tree.leaves(grad(False)(params))):
+        np.testing.assert_array_equal(a, b_)
+
+    # the census sees what the plain gathers do, remat'd with no policy:
+    # the forward's two sorts again in the recompute, the broadcast in
+    # both, and the scatters that are the two gathers' transposes
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 24, d), jnp.bfloat16)
+    p = params["blocks"]["p0_attn"]
+    weights = [p[n][0] for n in ("router", "we_g", "we_u", "we_d")]
+
+    def scanned(x, *w):
+        body = jax.checkpoint(lambda h, _: (h + _plain_gathers(h, *w, k)[0], None),
+                              prevent_cse=False)
+        return jnp.sum(jax.lax.scan(body, x, None, length=1)[0].astype(jnp.float32))
+
+    seen = permutation_census(jax.jit(jax.grad(scanned, argnums=(0, 1, 2, 3, 4))).lower(
+        x, *weights).as_text(), t, k, d)
+    assert seen == {"sorts": 4, "broadcasts": 2, "scatters": 2}
+
+
+def test_remat_policy_leaves_a_dense_block_as_it_was():
+    """The block's save-only-the-routing policy names nothing a dense block
+    computes, so a dense block's gradient lowers to the same text with it
+    as without; a MoE block's does not."""
+    policy = jax.checkpoint_policies.save_only_these_names(*moe.ROUTING)
+
+    def lowered(cfg, params, policy):
+        block_p = jax.tree.map(lambda a: a[0], params["blocks"]["p0_attn"])
+        x = jax.random.normal(jax.random.PRNGKey(3), (2, 24, cfg.d_model), jnp.bfloat16)
+        positions = jnp.arange(x.shape[1])[None, :]
+
+        def block(x, p):
+            return T._layer_fwd("attn", p, x, cfg, NULL_CTX, positions=positions,
+                                kv_block=0)[0]
+
+        body = jax.checkpoint(block, prevent_cse=False, policy=policy)
+        loss = lambda x, p: jnp.sum(body(x, p).astype(jnp.float32))  # noqa: E731
+        return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, block_p).as_text()
+
+    dense = dataclasses.replace(get_smoke_config("h2o-danube-1.8b"), n_layers=1)
+    dense_params = T.init_params(dense, jax.random.PRNGKey(0))
+    assert lowered(dense, dense_params, None) == lowered(dense, dense_params, policy)
+    cfg, params, _ = _granite_block()
+    assert lowered(cfg, params, None) != lowered(cfg, params, policy)
 
 
 def test_load_balance_term_and_load():
